@@ -1,11 +1,1 @@
-"""FCP reproduction package.
-
-Importing ``repro`` installs the JAX version-compatibility shims
-(:mod:`repro.compat`) so every entry point — tests, benchmarks, examples,
-launchers — sees a uniform modern JAX surface regardless of the installed
-release.
-"""
-
-from . import compat as _compat
-
-_compat.install()
+"""FCP reproduction package."""

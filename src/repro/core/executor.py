@@ -63,9 +63,9 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..kernels import ops
 from ..kernels.ref import NEG_INF
 from ..runtime import wire as wirelib

@@ -45,6 +45,15 @@ from .ref import NEG_INF, PAD_SEGMENT
 def _vmem_scratch(shape):
     return pltpu.VMEM(shape, jnp.float32)
 
+
+def _rows(x):
+    """Per-row vector [..., n] -> [..., 1, n]: the kernel-side layout of
+    segment ids, positions, lse and delta.  Mosaic needs the last two
+    block dims divisible by (8, 128) or equal to the array's; a singleton
+    second-minor axis makes a (1, block) tile of a row legal."""
+    return x[..., None, :]
+
+
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
 
@@ -77,8 +86,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, sq_ref, pq_ref, sk_ref, pk_ref,
     v = v_ref[0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    valid = _mask_tile(sq_ref[...], pq_ref[...], sk_ref[...],
-                       pk_ref[...], mask)
+    valid = _mask_tile(sq_ref[0], pq_ref[0], sk_ref[0], pk_ref[0], mask)
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]                        # [bq]
@@ -96,7 +104,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, sq_ref, pq_ref, sk_ref, pk_ref,
         safe = jnp.maximum(l, 1e-37)
         o_ref[0] = jnp.where(l[:, None] > 0, acc_ref[...] / safe[:, None],
                              0.0).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(l > 0, m_ref[...] + jnp.log(safe), NEG_INF)
+        lse_ref[0, 0] = jnp.where(l > 0, m_ref[...] + jnp.log(safe),
+                                  NEG_INF)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -123,7 +132,7 @@ def flash_attention_fwd(q, k, v, seg_q, pos_q, seg_k, pos_k, *,
 
     kernel = functools.partial(_fwd_kernel, scale=scale, mask=mask,
                                n_kv_tiles=n_k)
-    return pl.pallas_call(
+    o, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -132,18 +141,18 @@ def flash_attention_fwd(q, k, v, seg_q, pos_q, seg_k, pos_k, *,
                          lambda hh, i, j, g=group: (hh // g, j, 0)),
             pl.BlockSpec((1, block_k, d),
                          lambda hh, i, j, g=group: (hh // g, j, 0)),
-            pl.BlockSpec((block_q,), lambda hh, i, j: (i,)),
-            pl.BlockSpec((block_q,), lambda hh, i, j: (i,)),
-            pl.BlockSpec((block_k,), lambda hh, i, j: (j,)),
-            pl.BlockSpec((block_k,), lambda hh, i, j: (j,)),
+            pl.BlockSpec((1, block_q), lambda hh, i, j: (0, i)),
+            pl.BlockSpec((1, block_q), lambda hh, i, j: (0, i)),
+            pl.BlockSpec((1, block_k), lambda hh, i, j: (0, j)),
+            pl.BlockSpec((1, block_k), lambda hh, i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda hh, i, j: (hh, i, 0)),
-            pl.BlockSpec((1, block_q), lambda hh, i, j: (hh, i)),
+            pl.BlockSpec((1, 1, block_q), lambda hh, i, j: (hh, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((h, sq, d), jnp.float32),
-            jax.ShapeDtypeStruct((h, sq), jnp.float32),
+            jax.ShapeDtypeStruct((h, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
             # f32 accumulators living across the kv grid dimension
@@ -152,7 +161,8 @@ def flash_attention_fwd(q, k, v, seg_q, pos_q, seg_k, pos_k, *,
             _vmem_scratch((block_q,)),
         ],
         interpret=interpret,
-    )(q, k, v, seg_q, pos_q, seg_k, pos_k)
+    )(q, k, v, _rows(seg_q), _rows(pos_q), _rows(seg_k), _rows(pos_k))
+    return o, lse[:, 0]
 
 
 # --------------------------------------------------------------------------
@@ -173,14 +183,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, sq_ref, pq_ref, sk_ref, pk_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    delta = delta_ref[0]
-    dlse = dlse_ref[0]
+    lse = lse_ref[0, 0]
+    delta = delta_ref[0, 0]
+    dlse = dlse_ref[0, 0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    valid = _mask_tile(sq_ref[...], pq_ref[...], sk_ref[...],
-                       pk_ref[...], mask)
+    valid = _mask_tile(sq_ref[0], pq_ref[0], sk_ref[0], pk_ref[0], mask)
     p = jnp.where(valid, jnp.exp(s - lse[:, None]), 0.0)
     dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
@@ -213,14 +222,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, sq_ref, pq_ref, sk_ref, pk_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    delta = delta_ref[0]
-    dlse = dlse_ref[0]
+    lse = lse_ref[0, 0]
+    delta = delta_ref[0, 0]
+    dlse = dlse_ref[0, 0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    valid = _mask_tile(sq_ref[...], pq_ref[...], sk_ref[...],
-                       pk_ref[...], mask)
+    valid = _mask_tile(sq_ref[0], pq_ref[0], sk_ref[0], pk_ref[0], mask)
     p = jnp.where(valid, jnp.exp(s - lse[:, None]), 0.0)       # [bq, bk]
     dv_acc[...] += jax.lax.dot_general(
         p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -260,6 +268,8 @@ def flash_attention_bwd(q, k, v, seg_q, pos_q, seg_k, pos_k, o, lse,
     n_q, n_k = sq // block_q, sk // block_k
     do32 = do.astype(jnp.float32)
     delta = jnp.sum(do32 * o.astype(jnp.float32), axis=-1)    # [H, Sq]
+    row_args = (q, k, v, _rows(seg_q), _rows(pos_q), _rows(seg_k),
+                _rows(pos_k), _rows(lse), do, _rows(delta), _rows(dlse))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, mask=mask,
@@ -271,20 +281,20 @@ def flash_attention_bwd(q, k, v, seg_q, pos_q, seg_k, pos_k, o, lse,
                          lambda hh, i, j, g=group: (hh // g, j, 0)),
             pl.BlockSpec((1, block_k, d),
                          lambda hh, i, j, g=group: (hh // g, j, 0)),
-            pl.BlockSpec((block_q,), lambda hh, i, j: (i,)),
-            pl.BlockSpec((block_q,), lambda hh, i, j: (i,)),
-            pl.BlockSpec((block_k,), lambda hh, i, j: (j,)),
-            pl.BlockSpec((block_k,), lambda hh, i, j: (j,)),
-            pl.BlockSpec((1, block_q), lambda hh, i, j: (hh, i)),
+            pl.BlockSpec((1, block_q), lambda hh, i, j: (0, i)),
+            pl.BlockSpec((1, block_q), lambda hh, i, j: (0, i)),
+            pl.BlockSpec((1, block_k), lambda hh, i, j: (0, j)),
+            pl.BlockSpec((1, block_k), lambda hh, i, j: (0, j)),
+            pl.BlockSpec((1, 1, block_q), lambda hh, i, j: (hh, 0, i)),
             pl.BlockSpec((1, block_q, d), lambda hh, i, j: (hh, i, 0)),
-            pl.BlockSpec((1, block_q), lambda hh, i, j: (hh, i)),
-            pl.BlockSpec((1, block_q), lambda hh, i, j: (hh, i)),
+            pl.BlockSpec((1, 1, block_q), lambda hh, i, j: (hh, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda hh, i, j: (hh, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda hh, i, j: (hh, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[_vmem_scratch((block_q, d))],
         interpret=interpret,
-    )(q, k, v, seg_q, pos_q, seg_k, pos_k, lse, do, delta, dlse)
+    )(*row_args)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, mask=mask,
@@ -295,18 +305,18 @@ def flash_attention_bwd(q, k, v, seg_q, pos_q, seg_k, pos_k, o, lse,
                          lambda kk, j, g, i, gr=group: (kk * gr + g, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda kk, j, g, i: (kk, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda kk, j, g, i: (kk, j, 0)),
-            pl.BlockSpec((block_q,), lambda kk, j, g, i: (i,)),
-            pl.BlockSpec((block_q,), lambda kk, j, g, i: (i,)),
-            pl.BlockSpec((block_k,), lambda kk, j, g, i: (j,)),
-            pl.BlockSpec((block_k,), lambda kk, j, g, i: (j,)),
-            pl.BlockSpec((1, block_q),
-                         lambda kk, j, g, i, gr=group: (kk * gr + g, i)),
+            pl.BlockSpec((1, block_q), lambda kk, j, g, i: (0, i)),
+            pl.BlockSpec((1, block_q), lambda kk, j, g, i: (0, i)),
+            pl.BlockSpec((1, block_k), lambda kk, j, g, i: (0, j)),
+            pl.BlockSpec((1, block_k), lambda kk, j, g, i: (0, j)),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda kk, j, g, i, gr=group: (kk * gr + g, 0, i)),
             pl.BlockSpec((1, block_q, d),
                          lambda kk, j, g, i, gr=group: (kk * gr + g, i, 0)),
-            pl.BlockSpec((1, block_q),
-                         lambda kk, j, g, i, gr=group: (kk * gr + g, i)),
-            pl.BlockSpec((1, block_q),
-                         lambda kk, j, g, i, gr=group: (kk * gr + g, i)),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda kk, j, g, i, gr=group: (kk * gr + g, 0, i)),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda kk, j, g, i, gr=group: (kk * gr + g, 0, i)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda kk, j, g, i: (kk, j, 0)),
@@ -319,7 +329,7 @@ def flash_attention_bwd(q, k, v, seg_q, pos_q, seg_k, pos_k, o, lse,
         scratch_shapes=[_vmem_scratch((block_k, d)),
                         _vmem_scratch((block_k, d))],
         interpret=interpret,
-    )(q, k, v, seg_q, pos_q, seg_k, pos_k, lse, do, delta, dlse)
+    )(*row_args)
 
     return dq, dk, dv
 
@@ -362,7 +372,7 @@ def _fused_fwd_kernel(sq_tab, skv_tab, q_ref, k_ref, v_ref, qs_ref, qp_ref,
         # incoming accumulator == one normalized partial of weight 1:
         # o = acc/l, lse = m + log l  ⇒  (acc, m, l) = (o_in, lse_in, 1)
         acc_ref[...] = ai_o_ref[0, 0].astype(jnp.float32)
-        m_ref[...] = ai_l_ref[0, 0]
+        m_ref[...] = ai_l_ref[0, 0, 0]
         l_ref[...] = jnp.ones_like(l_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)        # [bq, d]
@@ -370,7 +380,8 @@ def _fused_fwd_kernel(sq_tab, skv_tab, q_ref, k_ref, v_ref, qs_ref, qp_ref,
     v = v_ref[0, 0].astype(jnp.float32)
     sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32) * scale
-    valid = _mask_tile(qs_ref[0], qp_ref[0], ks_ref[0], kp_ref[0], mask)
+    valid = _mask_tile(qs_ref[0, 0], qp_ref[0, 0], ks_ref[0, 0],
+                       kp_ref[0, 0], mask)
     sc = jnp.where(valid, sc, NEG_INF)
 
     m_prev = m_ref[...]
@@ -386,7 +397,7 @@ def _fused_fwd_kernel(sq_tab, skv_tab, q_ref, k_ref, v_ref, qs_ref, qp_ref,
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-37)     # >= alpha·1 + mass > 0
         o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
+        lse_ref[0, 0, 0] = m_ref[...] + jnp.log(l)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -435,24 +446,24 @@ def fused_flash_fwd(step_q, step_kv, qs, kxt, vxt, q_seg, q_pos,
             pl.BlockSpec((1, 1, block_k, d),
                          lambda hh, qi, s, kj, sq, skv, g=group:
                          (skv[s], hh // g, kj, 0)),
-            pl.BlockSpec((1, block_q),
-                         lambda hh, qi, s, kj, sq, skv: (sq[s], qi)),
-            pl.BlockSpec((1, block_q),
-                         lambda hh, qi, s, kj, sq, skv: (sq[s], qi)),
-            pl.BlockSpec((1, block_k),
-                         lambda hh, qi, s, kj, sq, skv: (s, kj)),
-            pl.BlockSpec((1, block_k),
-                         lambda hh, qi, s, kj, sq, skv: (s, kj)),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda hh, qi, s, kj, sq, skv: (sq[s], 0, qi)),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda hh, qi, s, kj, sq, skv: (sq[s], 0, qi)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda hh, qi, s, kj, sq, skv: (s, 0, kj)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda hh, qi, s, kj, sq, skv: (s, 0, kj)),
             pl.BlockSpec((1, 1, block_q, d),
                          lambda hh, qi, s, kj, sq, skv: (sq[s], hh, qi, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda hh, qi, s, kj, sq, skv: (sq[s], hh, qi)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda hh, qi, s, kj, sq, skv: (sq[s], hh, 0, qi)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda hh, qi, s, kj, sq, skv: (sq[s], hh, qi, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda hh, qi, s, kj, sq, skv: (sq[s], hh, qi)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda hh, qi, s, kj, sq, skv: (sq[s], hh, 0, qi)),
         ],
         scratch_shapes=[
             _vmem_scratch((block_q, d)),
@@ -460,16 +471,17 @@ def fused_flash_fwd(step_q, step_kv, qs, kxt, vxt, q_seg, q_pos,
             _vmem_scratch((block_q,)),
         ],
     )
-    return pl.pallas_call(
+    o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((sl, h, bs, d), jnp.float32),
-            jax.ShapeDtypeStruct((sl, h, bs), jnp.float32),
+            jax.ShapeDtypeStruct((sl, h, 1, bs), jnp.float32),
         ],
         interpret=interpret,
-    )(step_q, step_kv, qs, kxt, vxt, q_seg, q_pos, k_seg, k_pos,
-      acc_o, acc_lse)
+    )(step_q, step_kv, qs, kxt, vxt, _rows(q_seg), _rows(q_pos),
+      _rows(k_seg), _rows(k_pos), acc_o, _rows(acc_lse))
+    return o, lse[:, :, 0]
 
 
 def _fused_dq_kernel(sq_tab, skv_tab, q_ref, k_ref, v_ref, qs_ref, qp_ref,
@@ -497,12 +509,13 @@ def _fused_dq_kernel(sq_tab, skv_tab, q_ref, k_ref, v_ref, qs_ref, qp_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     go = go_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]
-    delta = dl_ref[0, 0]
+    lse = lse_ref[0, 0, 0]
+    delta = dl_ref[0, 0, 0]
 
     sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32) * scale
-    valid = _mask_tile(qs_ref[0], qp_ref[0], ks_ref[0], kp_ref[0], mask)
+    valid = _mask_tile(qs_ref[0, 0], qp_ref[0, 0], ks_ref[0, 0],
+                       kp_ref[0, 0], mask)
     p = jnp.where(valid, jnp.exp(sc - lse[:, None]), 0.0)
     dov = jax.lax.dot_general(go, v, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
@@ -558,20 +571,20 @@ def fused_flash_bwd_dq(step_q, step_kv, qs, kxt, vxt, q_seg, q_pos,
             pl.BlockSpec((1, 1, block_k, d),
                          lambda hh, qi, s, kj, sq, skv, g=group:
                          (skv[s], hh // g, kj, 0)),
-            pl.BlockSpec((1, block_q),
-                         lambda hh, qi, s, kj, sq, skv: (sq[s], qi)),
-            pl.BlockSpec((1, block_q),
-                         lambda hh, qi, s, kj, sq, skv: (sq[s], qi)),
-            pl.BlockSpec((1, block_k),
-                         lambda hh, qi, s, kj, sq, skv: (s, kj)),
-            pl.BlockSpec((1, block_k),
-                         lambda hh, qi, s, kj, sq, skv: (s, kj)),
             pl.BlockSpec((1, 1, block_q),
-                         lambda hh, qi, s, kj, sq, skv: (sq[s], hh, qi)),
+                         lambda hh, qi, s, kj, sq, skv: (sq[s], 0, qi)),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda hh, qi, s, kj, sq, skv: (sq[s], 0, qi)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda hh, qi, s, kj, sq, skv: (s, 0, kj)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda hh, qi, s, kj, sq, skv: (s, 0, kj)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda hh, qi, s, kj, sq, skv: (sq[s], hh, 0, qi)),
             pl.BlockSpec((1, 1, block_q, d),
                          lambda hh, qi, s, kj, sq, skv: (sq[s], hh, qi, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda hh, qi, s, kj, sq, skv: (sq[s], hh, qi)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda hh, qi, s, kj, sq, skv: (sq[s], hh, 0, qi)),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, block_q, d),
@@ -583,8 +596,8 @@ def fused_flash_bwd_dq(step_q, step_kv, qs, kxt, vxt, q_seg, q_pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((sl, h, bs, d), jnp.float32),
         interpret=interpret,
-    )(step_q, step_kv, qs, kxt, vxt, q_seg, q_pos, k_seg, k_pos,
-      lse, go, delta)
+    )(step_q, step_kv, qs, kxt, vxt, _rows(q_seg), _rows(q_pos),
+      _rows(k_seg), _rows(k_pos), _rows(lse), go, _rows(delta))
 
 
 def _fused_dkv_kernel(bq_tab, bkv_tab, q_ref, k_ref, v_ref, qs_ref, qp_ref,
@@ -613,12 +626,13 @@ def _fused_dkv_kernel(bq_tab, bkv_tab, q_ref, k_ref, v_ref, qs_ref, qp_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     go = go_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]
-    delta = dl_ref[0, 0]
+    lse = lse_ref[0, 0, 0]
+    delta = dl_ref[0, 0, 0]
 
     sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32) * scale
-    valid = _mask_tile(qs_ref[0], qp_ref[0], ks_ref[0], kp_ref[0], mask)
+    valid = _mask_tile(qs_ref[0, 0], qp_ref[0, 0], ks_ref[0, 0],
+                       kp_ref[0, 0], mask)
     p = jnp.where(valid, jnp.exp(sc - lse[:, None]), 0.0)      # [bq, bk]
     dv_acc[...] += jax.lax.dot_general(
         p, go, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -678,23 +692,23 @@ def fused_flash_bwd_dkv(bwd_q, bwd_kv, qs, kxt, vxt, q_seg, q_pos,
                          lambda kk, kj, s, g, i, bq, bkv: (bkv[s], kk, kj, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda kk, kj, s, g, i, bq, bkv: (bkv[s], kk, kj, 0)),
-            pl.BlockSpec((1, block_q),
-                         lambda kk, kj, s, g, i, bq, bkv: (bq[s], i)),
-            pl.BlockSpec((1, block_q),
-                         lambda kk, kj, s, g, i, bq, bkv: (bq[s], i)),
-            pl.BlockSpec((1, block_k),
-                         lambda kk, kj, s, g, i, bq, bkv: (s, kj)),
-            pl.BlockSpec((1, block_k),
-                         lambda kk, kj, s, g, i, bq, bkv: (s, kj)),
             pl.BlockSpec((1, 1, block_q),
+                         lambda kk, kj, s, g, i, bq, bkv: (bq[s], 0, i)),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda kk, kj, s, g, i, bq, bkv: (bq[s], 0, i)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda kk, kj, s, g, i, bq, bkv: (s, 0, kj)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda kk, kj, s, g, i, bq, bkv: (s, 0, kj)),
+            pl.BlockSpec((1, 1, 1, block_q),
                          lambda kk, kj, s, g, i, bq, bkv, gr=group:
-                         (bq[s], kk * gr + g, i)),
+                         (bq[s], kk * gr + g, 0, i)),
             pl.BlockSpec((1, 1, block_q, d),
                          lambda kk, kj, s, g, i, bq, bkv, gr=group:
                          (bq[s], kk * gr + g, i, 0)),
-            pl.BlockSpec((1, 1, block_q),
+            pl.BlockSpec((1, 1, 1, block_q),
                          lambda kk, kj, s, g, i, bq, bkv, gr=group:
-                         (bq[s], kk * gr + g, i)),
+                         (bq[s], kk * gr + g, 0, i)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d),
@@ -713,5 +727,5 @@ def fused_flash_bwd_dkv(bwd_q, bwd_kv, qs, kxt, vxt, q_seg, q_pos,
             jax.ShapeDtypeStruct((ex, kh, bs, d), jnp.float32),
         ],
         interpret=interpret,
-    )(bwd_q, bwd_kv, qs, kxt, vxt, q_seg, q_pos, k_seg, k_pos,
-      lse, go, delta)
+    )(bwd_q, bwd_kv, qs, kxt, vxt, _rows(q_seg), _rows(q_pos),
+      _rows(k_seg), _rows(k_pos), _rows(lse), go, _rows(delta))

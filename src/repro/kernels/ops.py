@@ -48,6 +48,13 @@ class KernelConfig:
     interpret: bool = False
     xla_chunk: int = 512
 
+    def __post_init__(self):
+        # interpret mode is the CPU stand-in for the chip; on the chip
+        # it would silently run the kernel body as slow XLA ops
+        if self.interpret and jax.default_backend() == "tpu":
+            raise ValueError("Pallas interpret mode requested on a TPU "
+                             "backend; run the compiled kernel instead")
+
 
 def _float0(x):
     return np.zeros(x.shape, dtype=jax.dtypes.float0)

@@ -12,15 +12,8 @@ import jax
 
 def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]
           ) -> jax.sharding.Mesh:
-    # ``jax.sharding.AxisType`` only exists on newer JAX; on 0.4.x every
-    # axis is Auto already.  ``repro.compat.install()`` (run on package
-    # import) backfills the enum and makes ``make_mesh`` tolerate the
-    # kwarg, so the getattr guard only matters if jax was patched away.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

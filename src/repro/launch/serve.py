@@ -37,6 +37,7 @@ from ..models import hybrid as hybridlib
 from ..models import ssm as ssmlib
 from ..models import transformer as tflib
 from ..parallel import sharding as sh
+from .compile_cache import enable_compile_cache
 
 
 def cache_specs(cfg: ModelConfig, mesh, kind: str):
@@ -242,6 +243,7 @@ def main(argv=None):
                    help="max prompt length in the synthetic stream")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     dims = [int(x) for x in args.mesh.split("x")]
     axes = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")

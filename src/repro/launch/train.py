@@ -31,12 +31,13 @@ from ..core import plan_cache as pc
 from ..core.schedule import Schedule, make_schedule
 from ..data.loader import Batch, LoaderState, SyntheticLoader
 from ..masks import MaskSpec, coerce_mask, parse_mask
-from ..models import Model, dense_attn_fn
+from ..models import Model
 from ..optimizer import adamw, schedules
 from ..parallel import sharding as sh
 from ..runtime import compression
 from ..runtime import elastic
 from ..runtime import health as health_mod
+from .compile_cache import enable_compile_cache
 
 
 def make_fcp_attn_fn(sched: Schedule, mesh, pcfg: ParallelConfig
@@ -226,13 +227,30 @@ def build_train_step(model: Model, mesh, pcfg: ParallelConfig,
     return train_step
 
 
-def jit_train_step(train_step, mesh, params_like, opt_like, residual_like,
-                   batch_like, fsdp: bool = True):
+def _state_shardings(params_like, opt_like, mesh, fsdp: bool = True):
     psh = sh.param_shardings(params_like, mesh, fsdp=fsdp)
     osh = adamw.AdamWState(
         step=NamedSharding(mesh, P()),
         m=sh.param_shardings(opt_like.m, mesh, fsdp=fsdp),
         v=sh.param_shardings(opt_like.v, mesh, fsdp=fsdp))
+    return psh, osh
+
+
+def init_state(model: Model, mesh, seed: int):
+    """Parameters and AdamW state, created in their train shardings so
+    no device ever holds the whole unsharded model (on one chip the
+    full ``stablelm_1_6b`` state alone exceeds a v5e's HBM)."""
+    key = jax.random.key(seed)
+    p_like = jax.eval_shape(model.init, key)
+    o_like = jax.eval_shape(adamw.init, p_like)
+    psh, osh = _state_shardings(p_like, o_like, mesh)
+    params = jax.jit(model.init, out_shardings=psh)(key)
+    return params, jax.jit(adamw.init, out_shardings=osh)(params)
+
+
+def jit_train_step(train_step, mesh, params_like, opt_like, residual_like,
+                   batch_like, fsdp: bool = True):
+    psh, osh = _state_shardings(params_like, opt_like, mesh, fsdp)
     rsh = (sh.param_shardings(residual_like, mesh, fsdp=fsdp)
            if residual_like is not None else None)
     bsh = sh.batch_shardings(batch_like, mesh)
@@ -384,8 +402,9 @@ class Supervisor:
         self.manager = (CheckpointManager(checkpoint_dir,
                                           keep_n=checkpoint_keep)
                         if checkpoint_dir else None)
-        self.params = self.model.init(jax.random.key(tcfg.seed))
-        self.opt = adamw.init(self.params)
+        self._meshes: dict = {}
+        self.params, self.opt = init_state(
+            self.model, self._mesh(self.pods, self.n), tcfg.seed)
         self.residual = (compression.init_residuals(self.params)
                          if tcfg.grad_compression else None)
         # host copies of step 0 (np.array forces real copies — the live
@@ -398,7 +417,6 @@ class Supervisor:
         self.group_masks = list(dict.fromkeys(self.layer_masks))
         nh, nkv = cfg.padded_heads(1)
         self._heads = (max(nh, 1), max(nkv, 1), max(cfg.head_dim, 1))
-        self._meshes: dict = {}
         self._step_cache: dict = {}
         self.compiled_at: list[int] = []     # steps that built a new jit
         self.history: list[StepRecord] = []
@@ -819,7 +837,21 @@ class Supervisor:
 # CLI driver
 # --------------------------------------------------------------------------
 
-def main(argv=None):
+@dataclasses.dataclass
+class TrainResult:
+    """What :func:`main` ran: per-step losses, the live state, and (plain
+    loop only) the last jitted step with the batch it took, so a caller
+    can inspect the compiled program."""
+    losses: list[float]
+    params: dict
+    opt: adamw.AdamWState
+    plan_misses: int                  # FCP plans built; 0 = no executor
+    residual: dict | None = None
+    step_fn: Callable | None = None
+    batch: dict | None = None
+
+
+def main(argv=None) -> TrainResult:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--shape", default=None,
@@ -912,6 +944,7 @@ def main(argv=None):
                         " (rate-limits plan churn)")
     p.add_argument("--log-every", type=int, default=1)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     dims = [int(x) for x in args.mesh.split("x")]
     if len(dims) == 2:
@@ -972,7 +1005,9 @@ def main(argv=None):
               f"{len(summary['recoveries'])} recover(ies), "
               f"{summary['compiles']} compiles")
         print("done.")
-        return
+        return TrainResult(losses=[r.loss for r in sup.history],
+                           params=sup.params, opt=sup.opt,
+                           plan_misses=s.misses)
 
     model = Model(cfg, tp=tp)
     loader = SyntheticLoader(
@@ -982,8 +1017,7 @@ def main(argv=None):
         plan_buckets=pcfg.plan_buckets, bucket_min_len=pcfg.block_size,
         fresh=args.fresh_stream)
 
-    params = model.init(jax.random.key(tcfg.seed))
-    opt = adamw.init(params)
+    params, opt = init_state(model, mesh, tcfg.seed)
     residual = (compression.init_residuals(params)
                 if tcfg.grad_compression else None)
 
@@ -992,7 +1026,9 @@ def main(argv=None):
     # batch t+1 is planned on a host thread while batch t executes
     plan_cache = pc.PlanCache(pcfg.plan_cache_size)
     planner = pc.PlanAheadPlanner(plan_cache, enabled=pcfg.plan_ahead)
-    fcp = cfg.uses_attention and n_cp > 1
+    # FCP on every attention model, one worker included: the planner,
+    # plan cache and executor are the training path on one chip too
+    fcp = cfg.uses_attention
     # per-layer-group scheduling: one FCP schedule (and one plan-cache
     # key) per distinct mask family in the model; layers route to their
     # group's attention closure
@@ -1011,6 +1047,8 @@ def main(argv=None):
     if args.checkpoint_dir:
         mgr = CheckpointManager(args.checkpoint_dir)
 
+    losses = []
+    step_fn = batch = None
     t0 = time.time()
     for step in range(args.steps):
         b = loader.next()
@@ -1030,29 +1068,25 @@ def main(argv=None):
         else:
             key, scheds = b.composition_id, None
         if key not in step_cache:
-            if not cfg.uses_attention:
+            if not fcp:
                 attn = None
-            elif fcp and pcfg.layer_pipeline:
+            elif pcfg.layer_pipeline:
                 attn = make_pipelined_attn_fns(cfg, pcfg, layer_masks,
                                                scheds, mesh)
-            elif fcp:
+            else:
                 attn = route_layers(
                     cfg, layer_masks, group_masks,
                     lambda m: make_fcp_attn_fn(scheds[m], mesh, pcfg))
-            else:
-                seg_j = jnp.asarray(b.seg_ids)
-                attn = route_layers(
-                    cfg, layer_masks, group_masks,
-                    lambda m: dense_attn_fn(seg_j, batch["positions"],
-                                            mask=m))
             ts = build_train_step(model, mesh, pcfg, tcfg, attn)
             step_cache[key] = jit_train_step(
                 ts, mesh, params, opt, residual, batch)
             while len(step_cache) > max(pcfg.plan_cache_size, 1):
                 # bound compiled-step retention like the plan cache
                 step_cache.pop(next(iter(step_cache)))
-        params, opt, residual, loss, gnorm = step_cache[key](
+        step_fn = step_cache[key]
+        params, opt, residual, loss, gnorm = step_fn(
             params, opt, residual, batch)
+        losses.append(loss)
         if step % args.log_every == 0:
             print(f"step {step:5d}  loss {float(loss):.4f}  "
                   f"gnorm {float(gnorm):.3f}  "
@@ -1064,13 +1098,16 @@ def main(argv=None):
     planner.shutdown()
     if mgr:
         mgr.wait()
+    s = plan_cache.stats
     if fcp:
-        s = plan_cache.stats
         print(f"plan cache: {s.hits} hits / {s.misses} misses "
               f"(hit rate {s.hit_rate:.2f}), "
               f"{plan_cache.n_unique_specs} static specs, "
               f"{planner.prefetched_hits} plan-ahead builds consumed")
     print("done.")
+    return TrainResult(losses=[float(x) for x in losses], params=params,
+                       opt=opt, residual=residual, plan_misses=s.misses,
+                       step_fn=step_fn, batch=batch)
 
 
 if __name__ == "__main__":

@@ -29,10 +29,10 @@ os.environ.setdefault("XLA_FLAGS",
 import jax                                                      # noqa: E402
 import jax.numpy as jnp                                         # noqa: E402
 import numpy as np                                              # noqa: E402
+from jax import shard_map                                       # noqa: E402
 from jax.sharding import PartitionSpec as P                     # noqa: E402
 
 from repro import masks                                         # noqa: E402
-from repro.compat import shard_map                              # noqa: E402
 from repro.core import executor, make_schedule                  # noqa: E402
 from repro.kernels import ref                                   # noqa: E402
 from repro.runtime import wire                                  # noqa: E402

@@ -2,6 +2,8 @@
 shardings, grad compression path, schedule-bucket compile caching, and
 speed-aware rebalancing wiring."""
 
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -173,3 +175,58 @@ def test_train_step_with_mixed_mask_pattern():
         losses.append(float(loss))
         assert np.isfinite(losses[-1])
     assert np.mean(losses[-3:]) < np.mean(losses[:3])
+
+
+def _restore_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_compilation_cache_dir", None)
+    compilation_cache.reset_cache()
+
+
+def test_one_worker_main_trains_through_executor(tmp_path, monkeypatch):
+    """``train.main`` on a 1x1 mesh trains through the planner and the
+    FCP executor (``fused_xla``), and its step-0 loss matches the dense
+    single-device oracle on the same parameters and batch."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        res = T.main(["--arch", "stablelm_1_6b", "--smoke", "--mesh", "1x1",
+                      "--attn-impl", "fused_xla", "--dist", "real_world",
+                      "--tokens-per-worker", "1024", "--block-size", "256",
+                      "--steps", "1", "--override", "param_dtype=float32"])
+    finally:
+        _restore_compile_cache()
+    assert res.plan_misses == 1          # a schedule was planned and run
+
+    cfg = smoke_config("stablelm_1_6b").replace(param_dtype="float32")
+    model = Model(cfg, tp=1)
+    tcfg = TrainConfig()
+    params, _ = T.init_state(model, make_mesh((1, 1), ("data", "model")),
+                             tcfg.seed)
+    b = SyntheticLoader(dist="real_world", n_frames=1,
+                        tokens_per_worker=1024, vocab_size=cfg.vocab_size,
+                        seed=tcfg.seed, bucket_min_len=256).next()
+    batch = T.batch_arrays(b, cfg)
+    attn = dense_attn_fn(jnp.asarray(b.seg_ids), batch["positions"])
+    oracle = float(jax.jit(lambda p: model.loss(p, batch, attn))(params))
+    # the suite's executor-vs-dense-oracle tolerance, normalized
+    assert abs(res.losses[0] - oracle) <= 1e-6 * max(1.0, abs(oracle)), \
+        (res.losses[0], oracle)
+
+
+def test_compile_cache_dir(tmp_path, monkeypatch):
+    """The entry points' compile cache lives where
+    ``JAX_COMPILATION_CACHE_DIR`` says, else at ``.jax_cache/`` in the
+    checkout (a fixed path: the cache key includes it)."""
+    from repro.launch.compile_cache import enable_compile_cache
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(
+            root / ".jax_cache")
+    finally:
+        _restore_compile_cache()
