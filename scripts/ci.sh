@@ -84,7 +84,7 @@ run_fault_drill() {
     install
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-        python -m pytest -q \
+        python -m pytest -q -m slow \
         tests/test_multidevice.py::test_fault_drill_multidevice
     mkdir -p bench_out
     PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \

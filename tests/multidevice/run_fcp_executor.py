@@ -6,7 +6,8 @@ rounds -> restore), and compares against the dense single-device oracle
 over the whole stream.  Also checks gradients.
 
 Usage: XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-       PYTHONPATH=src python tests/multidevice/run_fcp_executor.py
+       PYTHONPATH=src python tests/multidevice/run_fcp_executor.py \
+       [tier1|full]
 """
 
 import os
@@ -23,6 +24,7 @@ from repro.core import make_schedule                            # noqa: E402
 from repro.core import executor                                 # noqa: E402
 from repro.core import policies                                 # noqa: E402
 from repro.kernels import ref                                   # noqa: E402
+from _common import grid                                        # noqa: E402
 
 
 def run_case(seqlens, n_workers, tokens_per_worker, block_size, mesh_shape,
@@ -103,49 +105,56 @@ def run_case(seqlens, n_workers, tokens_per_worker, block_size, mesh_shape,
     return err
 
 
-def main():
-    cases = [
-        dict(seqlens=[512] * 16, n_workers=8, tokens_per_worker=1024,
-             block_size=256, mesh_shape=(8,), mesh_axes=("data",),
-             hq=4, kh=2, d=32, mask=True),                 # packed short
-        dict(seqlens=[4096, 2048, 1024, 512, 300, 200],
-             n_workers=8, tokens_per_worker=1024, block_size=256,
-             mesh_shape=(8,), mesh_axes=("data",),
-             hq=4, kh=2, d=32, mask=True),                 # long-tailed
-        dict(seqlens=[6000, 1500], n_workers=4, tokens_per_worker=2048,
-             block_size=512, mesh_shape=(4, 2), mesh_axes=("data", "model"),
-             hq=4, kh=2, d=32, mask=True),                 # CP x TP
-        dict(seqlens=[3000, 1000], n_workers=4, tokens_per_worker=1024,
-             block_size=256, mesh_shape=(2, 4), mesh_axes=("pod", "data"),
-             hq=2, kh=2, d=16, mask=True, n_pods=2),       # multi-pod DP
-        dict(seqlens=[2048, 1024, 512], n_workers=8,
-             tokens_per_worker=512, block_size=256, mesh_shape=(8,),
-             mesh_axes=("data",), hq=2, kh=1, d=16, mask=False),
-        dict(seqlens=[4096, 2048, 1024, 512, 300, 200],
-             n_workers=8, tokens_per_worker=1024, block_size=256,
-             mesh_shape=(8,), mesh_axes=("data",),
-             hq=4, kh=2, d=32, mask=True, policy="ring",
-             check_grad=False),                              # ring baseline
-    ]
-    for i, c in enumerate(cases):
-        err = run_case(**c, seed=100 + i)
-        print(f"case {i}: max fwd err {err:.2e}  OK")
+# a long-tailed causal batch: 16 blocks of 256 on 8 workers, 11
+# congestion-free rounds at C = 1, 3 at C = 4, 1 at C = 16
+LONG_TAILED = dict(seqlens=[2048, 1024, 512, 300, 212], n_workers=8,
+                   tokens_per_worker=512, block_size=256, mesh_shape=(8,),
+                   mesh_axes=("data",), hq=4, kh=2, d=32, mask=True)
 
-    # ---- §4.2 coalescer: C > 1 must match both the oracle and the
-    # C = 1 schedule of the same batch (same assignment, same pairs —
-    # only comm round structure changes)
-    base = dict(seqlens=[4096, 2048, 1024, 512, 300, 200], n_workers=8,
-                tokens_per_worker=1024, block_size=256, mesh_shape=(8,),
-                mesh_axes=("data",), hq=4, kh=2, d=32, mask=True)
-    _, o1 = run_case(**base, seed=7, check_grad=False, coalesce=1,
-                     return_out=True)
-    for C in (4, 16):
-        errc, oc = run_case(**base, seed=7, check_grad=(C == 4),
-                            coalesce=C, return_out=True)
+
+def check_full_grid(err4, o4):
+    # C > 1 must also match the C = 1 schedule of the same batch (same
+    # assignment, same pairs — only comm round structure changes)
+    err1, o1 = run_case(**LONG_TAILED, seed=7, coalesce=1, return_out=True)
+    print(f"coalesce=1: max fwd err {err1:.2e}  OK")
+    err16, o16 = run_case(**LONG_TAILED, seed=7, check_grad=False,
+                          coalesce=16, return_out=True)
+    for C, errc, oc in ((4, err4, o4), (16, err16, o16)):
         dev = np.abs(oc - o1).max()
         assert dev < 1e-4, f"coalesce={C} output drifted from C=1: {dev}"
         print(f"coalesce={C}: max fwd err {errc:.2e}  "
               f"|o - o(C=1)| {dev:.2e}  OK")
+
+    cases = [
+        dict(seqlens=[512] * 16, n_workers=8, tokens_per_worker=1024,
+             block_size=256, mesh_shape=(8,), mesh_axes=("data",),
+             hq=4, kh=2, d=32, mask=True, seed=100),       # packed short
+        dict(seqlens=[6000, 1500], n_workers=4, tokens_per_worker=2048,
+             block_size=512, mesh_shape=(4, 2), mesh_axes=("data", "model"),
+             hq=4, kh=2, d=32, mask=True, seed=102),       # CP x TP
+        dict(seqlens=[3000, 1000], n_workers=4, tokens_per_worker=1024,
+             block_size=256, mesh_shape=(2, 4), mesh_axes=("pod", "data"),
+             hq=2, kh=2, d=16, mask=True, n_pods=2,
+             seed=103),                                    # multi-pod DP
+        dict(seqlens=[2048, 1024, 512], n_workers=8,
+             tokens_per_worker=512, block_size=256, mesh_shape=(8,),
+             mesh_axes=("data",), hq=2, kh=1, d=16, mask=False,
+             seed=104),                                    # non-causal
+        dict(LONG_TAILED, policy="ring", check_grad=False,
+             seed=105),                                    # ring baseline
+    ]
+    for i, c in enumerate(cases):
+        err = run_case(**c)
+        print(f"case {i}: max fwd err {err:.2e}  OK")
+
+
+def main():
+    full = grid() == "full"
+    # §4.2 coalescer: C = 4 windows the rounds; vs the oracle, with grads
+    err4, o4 = run_case(**LONG_TAILED, seed=7, coalesce=4, return_out=True)
+    print(f"coalesce=4: max fwd err {err4:.2e}  OK")
+    if full:
+        check_full_grid(err4, o4)
     print("ALL MULTIDEVICE EXECUTOR CASES PASSED")
     return 0
 

@@ -7,7 +7,8 @@ random schedules and coalescer degrees, and its traced launch count must
 drop from ``n_steps`` to ``n_runs <= n_rounds + 1``.
 
 Usage: XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-       PYTHONPATH=src python tests/multidevice/run_fused_executor.py
+       PYTHONPATH=src python tests/multidevice/run_fused_executor.py \
+       [tier1|full]
 """
 
 import os
@@ -20,63 +21,28 @@ import jax                                                      # noqa: E402
 import jax.numpy as jnp                                         # noqa: E402
 import numpy as np                                              # noqa: E402
 
-from repro.core import make_schedule                            # noqa: E402
-from repro.core import executor                                 # noqa: E402
 from repro.kernels import ops                                   # noqa: E402
+from _common import build, exec_fn, grid, rel_err               # noqa: E402
 
 TOL = 4e-7          # fused-vs-per-step, normalized
 
 
-def build(seqlens, n_workers, tpw, bs, hq, kh, d, coalesce, seed):
-    sched = make_schedule(seqlens, n_workers, tpw, bs, n_q_heads=hq,
-                          n_kv_heads=kh, head_dim=d, mask=True,
-                          coalesce=coalesce)
-    rng = np.random.default_rng(seed)
-    total = sched.batch.n_tokens
-    q = jnp.asarray(rng.normal(size=(total, hq, d)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(total, kh, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(total, kh, d)), jnp.float32)
-    key = jnp.asarray(rng.normal(size=(total, hq, d)), jnp.float32)
-    return sched, q, k, v, key
-
-
-def run_fn(sched, mesh, tpw, impl, interpret=False, block=256):
-    tables = executor.schedule_tables(sched)
-    cfg = executor.ExecConfig(impl=impl, interpret=interpret,
-                              block_q=block, block_k=block)
-
-    def fcp(q, k, v):
-        total = q.shape[0]
-        F = total // tpw
-
-        def sh(x):
-            return x.reshape(F, tpw, x.shape[-2], x.shape[-1])
-
-        o = executor.fcp_attention(sh(q), sh(k), sh(v), tables,
-                                   spec=sched.spec, mesh=mesh,
-                                   cp_axis="data", head_axis=None, cfg=cfg)
-        return o.reshape(total, q.shape[-2], q.shape[-1])
-    return fcp
-
-
 def count_launches(sched, mesh, tpw, impl, q, k, v):
     """Trace the executor and count attention-op calls per worker."""
-    return ops.count_attention_launches(run_fn(sched, mesh, tpw, impl),
-                                        q, k, v)
+    return ops.count_attention_launches(
+        exec_fn(sched, mesh, tpw, impl=impl), q, k, v)
 
 
 def check_case(seqlens, n_workers, tpw, bs, hq, kh, d, coalesce, seed,
                check_grad):
     sched, q, k, v, key = build(seqlens, n_workers, tpw, bs, hq, kh, d,
-                                coalesce, seed)
+                                seed=seed, mask=True, coalesce=coalesce)
     spec = sched.spec
     mesh = jax.make_mesh((n_workers,), ("data",))
 
-    per_step = run_fn(sched, mesh, tpw, "xla")
-    fused = run_fn(sched, mesh, tpw, "fused_xla")
-    o_s = np.asarray(jax.jit(per_step)(q, k, v))
-    o_f = np.asarray(jax.jit(fused)(q, k, v))
-    err = np.abs(o_f - o_s).max() / max(1.0, np.abs(o_s).max())
+    per_step = exec_fn(sched, mesh, tpw, impl="xla")
+    fused = exec_fn(sched, mesh, tpw, impl="fused_xla")
+    err = rel_err(jax.jit(fused)(q, k, v), jax.jit(per_step)(q, k, v))
     assert err < TOL, f"C={coalesce}: fused output drifted {err:.2e}"
 
     gerrs = []
@@ -87,8 +53,7 @@ def check_case(seqlens, n_workers, tpw, bs, hq, kh, d, coalesce, seed,
         g_s = jax.jit(jax.grad(loss(per_step), argnums=(0, 1, 2)))(q, k, v)
         g_f = jax.jit(jax.grad(loss(fused), argnums=(0, 1, 2)))(q, k, v)
         for a, b, name in zip(g_f, g_s, "qkv"):
-            a, b = np.asarray(a), np.asarray(b)
-            gerr = np.abs(a - b).max() / max(1.0, np.abs(b).max())
+            gerr = rel_err(a, b)
             assert gerr < TOL, f"C={coalesce} d{name}: {gerr:.2e}"
             gerrs.append(gerr)
 
@@ -108,34 +73,45 @@ def check_case(seqlens, n_workers, tpw, bs, hq, kh, d, coalesce, seed,
     return c_step["step"], c_fused["fused"]
 
 
-def main():
-    # random long-tailed schedules (8 workers), the acceptance grid
-    rng = np.random.default_rng(0)
-    base = dict(n_workers=8, tpw=512, bs=256, hq=4, kh=2, d=32)
-    for case in range(2):
-        lens = []
-        budget = base["n_workers"] * base["tpw"]
-        while budget > 256:
-            L = int(min(np.clip(rng.lognormal(6.5, 1.2), 128, 3072), budget))
-            lens.append(L)
-            budget -= L
-        if budget:
-            lens.append(budget)
-        print(f"case {case}: seqlens={lens}")
-        for C in (1, 4, 16):
-            check_case(lens, **base, coalesce=C, seed=100 + case,
-                       check_grad=(case == 0))
+def lognormal_lens(rng, n_workers, tpw):
+    """Random long-tailed document lengths filling the batch."""
+    lens = []
+    budget = n_workers * tpw
+    while budget > 256:
+        L = int(min(np.clip(rng.lognormal(6.5, 1.2), 128, 3072), budget))
+        lens.append(L)
+        budget -= L
+    if budget:
+        lens.append(budget)
+    return lens
 
-    # fused Pallas kernel end-to-end (interpret mode), small case
-    lens = [512, 256, 128, 128]
-    sched, q, k, v, _ = build(lens, 4, 256, 128, 2, 1, 16, 4, 7)
+
+def check_fused_pallas():
+    """The fused Pallas kernel end to end (interpret mode), small case."""
+    sched, q, k, v, _ = build([512, 256, 128, 128], 4, 256, 128, 2, 1, 16,
+                              seed=7, mask=True, coalesce=4)
     mesh = jax.make_mesh((4,), ("data",))
-    o_s = np.asarray(jax.jit(run_fn(sched, mesh, 256, "xla"))(q, k, v))
-    o_p = np.asarray(jax.jit(run_fn(sched, mesh, 256, "fused",
-                                    interpret=True, block=128))(q, k, v))
-    err = np.abs(o_p - o_s).max() / max(1.0, np.abs(o_s).max())
+    o_s = jax.jit(exec_fn(sched, mesh, 256, impl="xla"))(q, k, v)
+    o_p = jax.jit(exec_fn(sched, mesh, 256, impl="fused", interpret=True,
+                          block_q=128, block_k=128))(q, k, v)
+    err = rel_err(o_p, o_s)
     assert err < 2e-6, f"fused-pallas executor drifted: {err:.2e}"
     print(f"fused pallas (interpret) end-to-end: |o - o_s| {err:.2e}")
+
+
+def main():
+    # random long-tailed schedules (8 workers): tier1 runs case 0 at
+    # C = 4 with grads, full the whole acceptance grid
+    full = grid() == "full"
+    rng = np.random.default_rng(0)
+    base = dict(n_workers=8, tpw=512, bs=256, hq=4, kh=2, d=32)
+    for case in range(2 if full else 1):
+        lens = lognormal_lens(rng, base["n_workers"], base["tpw"])
+        print(f"case {case}: seqlens={lens}")
+        for C in (1, 4, 16) if full else (4,):
+            check_case(lens, **base, coalesce=C, seed=100 + case,
+                       check_grad=(case == 0))
+    check_fused_pallas()
     print("ALL FUSED EXECUTOR CASES PASSED")
     return 0
 

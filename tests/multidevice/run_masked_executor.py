@@ -8,7 +8,8 @@ tentpole pruning property end-to-end: the sliding-window schedule ships
 strictly fewer comm edges than the causal schedule of the same batch.
 
 Usage: XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-       PYTHONPATH=src python tests/multidevice/run_masked_executor.py
+       PYTHONPATH=src python tests/multidevice/run_masked_executor.py \
+       [tier1|full]
 """
 
 import os
@@ -19,81 +20,34 @@ os.environ.setdefault("XLA_FLAGS",
 
 import jax                                                      # noqa: E402
 import jax.numpy as jnp                                         # noqa: E402
-import numpy as np                                              # noqa: E402
 
 from repro import masks                                         # noqa: E402
-from repro.core import executor, make_schedule                  # noqa: E402
-from repro.kernels import ref                                   # noqa: E402
+from repro.core import make_schedule                            # noqa: E402
+from _common import (build, exec_fn, grid, out_loss_grads,      # noqa: E402
+                     ref_fn, rel_err)
 
 TOL = 1e-6          # executor vs dense oracle, normalized
 
 
-def build(seqlens, n_workers, tpw, bs, hq, kh, d, mask, coalesce=4,
-          seed=0):
-    sched = make_schedule(seqlens, n_workers, tpw, bs, n_q_heads=hq,
-                          n_kv_heads=kh, head_dim=d, mask=mask,
-                          coalesce=coalesce)
-    rng = np.random.default_rng(seed)
-    total = sched.batch.n_tokens
-    mk = lambda h_: jnp.asarray(rng.normal(size=(total, h_, d)),  # noqa: E731
-                                jnp.float32)
-    return sched, mk(hq), mk(kh), mk(kh), mk(hq)
-
-
-def exec_fn(sched, mesh, tpw, impl="xla", interpret=False, block=128):
-    tables = executor.schedule_tables(sched)
-    cfg = executor.ExecConfig(impl=impl, interpret=interpret,
-                              block_q=block, block_k=block)
-
-    def fcp(q, k, v):
-        total = q.shape[0]
-        F = total // tpw
-
-        def sh(x):
-            return x.reshape(F, tpw, x.shape[-2], x.shape[-1])
-
-        o = executor.fcp_attention(sh(q), sh(k), sh(v), tables,
-                                   spec=sched.spec, mesh=mesh,
-                                   cp_axis="data", head_axis=None, cfg=cfg)
-        return o.reshape(total, q.shape[-2], q.shape[-1])
-    return fcp
-
-
-def ref_fn(sched, mask):
-    seg = jnp.asarray(sched.batch.seg_ids)
-    pos = jnp.asarray(sched.batch.positions)
-
-    def dense(q, k, v):
-        o, _ = ref.reference_attention(
-            q.transpose(1, 0, 2), k.transpose(1, 0, 2),
-            v.transpose(1, 0, 2), seg, pos, seg, pos, mask)
-        return o.transpose(1, 0, 2)
-    return dense
-
-
-def rel_err(a, b):
-    a, b = np.asarray(a), np.asarray(b)
-    return np.abs(a - b).max() / max(1.0, np.abs(b).max())
+def ref_grads(dense, q, k, v, key):
+    """Un-jitted q/k/v gradients of the oracle's ``sum(o * key)``."""
+    return jax.grad(lambda q, k, v: jnp.sum(dense(q, k, v) * key),
+                    argnums=(0, 1, 2))(q, k, v)
 
 
 def check_single_mask(seqlens, mask, n_workers=8, tpw=1024, bs=256, hq=4,
                       kh=2, d=32, impl="xla", interpret=False, seed=0):
     sched, q, k, v, key = build(seqlens, n_workers, tpw, bs, hq, kh, d,
-                                mask, seed=seed)
+                                seed=seed, mask=mask, coalesce=4)
     mesh = jax.make_mesh((n_workers,), ("data",))
-    fcp = exec_fn(sched, mesh, tpw, impl=impl, interpret=interpret)
+    fcp = exec_fn(sched, mesh, tpw, impl=impl, interpret=interpret,
+                  block_q=128, block_k=128)
     dense = ref_fn(sched, mask)
 
-    o = jax.jit(fcp)(q, k, v)
-    o_ref = dense(q, k, v)
-    err = rel_err(o, o_ref)
+    o, _, g_f = out_loss_grads(fcp, q, k, v, key)
+    err = rel_err(o, dense(q, k, v))
     assert err < TOL, f"{mask} fwd: {err:.2e}"
-
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(fn(q, k, v) * key)
-
-    g_f = jax.jit(jax.grad(loss(fcp), argnums=(0, 1, 2)))(q, k, v)
-    g_r = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    g_r = ref_grads(dense, q, k, v, key)
     gerr = max(rel_err(a, b) for a, b in zip(g_f, g_r))
     assert gerr < TOL, f"{mask} grad: {gerr:.2e}"
     print(f"  {str(mask):14s} [{impl}]  comm edges {len(sched.comm_edges):3d}"
@@ -107,14 +61,14 @@ def check_mixed_layer_groups(seqlens, mask_a, mask_b, n_workers=8,
     path's per-layer-group structure): layer 1 under ``mask_a``, layer 2
     under ``mask_b``, gradients flowing through both executors."""
     sched_a, q, k, v, key = build(seqlens, n_workers, tpw, bs, hq, kh, d,
-                                  mask_a, seed=seed)
+                                  seed=seed, mask=mask_a, coalesce=4)
     sched_b = make_schedule(seqlens, n_workers, tpw, bs, n_q_heads=hq,
                             n_kv_heads=kh, head_dim=d, mask=mask_b,
                             coalesce=4)
     assert sched_a.spec != sched_b.spec or mask_a == mask_b
     mesh = jax.make_mesh((n_workers,), ("data",))
-    fcp_a = exec_fn(sched_a, mesh, tpw)
-    fcp_b = exec_fn(sched_b, mesh, tpw)
+    fcp_a = exec_fn(sched_a, mesh, tpw, block_q=128, block_k=128)
+    fcp_b = exec_fn(sched_b, mesh, tpw, block_q=128, block_k=128)
     dense_a = ref_fn(sched_a, mask_a)
     dense_b = ref_fn(sched_b, mask_b)
     kh_take = k.shape[-2]
@@ -130,40 +84,21 @@ def check_mixed_layer_groups(seqlens, mask_a, mask_b, n_workers=8,
             return layer2(q2, k2, v2)
         return f
 
-    o = jax.jit(chain(fcp_a, fcp_b))(q, k, v)
-    o_ref = chain(dense_a, dense_b)(q, k, v)
-    err = rel_err(o, o_ref)
+    o, _, g_f = out_loss_grads(chain(fcp_a, fcp_b), q, k, v, key)
+    err = rel_err(o, chain(dense_a, dense_b)(q, k, v))
     assert err < TOL, f"mixed fwd: {err:.2e}"
-
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(fn(q, k, v) * key)
-
-    g_f = jax.jit(jax.grad(loss(chain(fcp_a, fcp_b)),
-                           argnums=(0, 1, 2)))(q, k, v)
-    g_r = jax.grad(loss(chain(dense_a, dense_b)),
-                   argnums=(0, 1, 2))(q, k, v)
+    g_r = ref_grads(chain(dense_a, dense_b), q, k, v, key)
     gerr = max(rel_err(a, b) for a, b in zip(g_f, g_r))
     assert gerr < TOL, f"mixed grad: {gerr:.2e}"
     print(f"  mixed {str(mask_a)} + {str(mask_b)}:  fwd {err:.2e}  "
           f"grad {gerr:.2e}  OK")
 
 
-def main():
+def check_full_grid():
     long_tailed = [4096, 2048, 1024, 512, 300, 200]
-    print("single-mask schedules vs dense oracle (fwd + grad):")
-    # W=1000: not a multiple of the 256 block — window cuts mid-block
-    s_swa = check_single_mask(long_tailed, masks.sliding_window(1000),
-                              seed=11)
-    s_causal = check_single_mask(long_tailed, masks.CAUSAL, seed=11)
     check_single_mask(long_tailed, masks.chunked(1024), seed=12)
     check_single_mask(long_tailed, masks.FULL, seed=13)
     check_single_mask([8192], masks.sliding_window(512), seed=14)
-    # the pruning property, end-to-end on identical batches
-    assert len(s_swa.comm_edges) < len(s_causal.comm_edges), \
-        (len(s_swa.comm_edges), len(s_causal.comm_edges))
-    print(f"  swa ships {len(s_swa.comm_edges)} comm edges < causal "
-          f"{len(s_causal.comm_edges)}  OK")
-
     # fused executor impl under a window mask
     check_single_mask(long_tailed, masks.sliding_window(1000),
                       impl="fused_xla", seed=15)
@@ -173,6 +108,23 @@ def main():
                              masks.CAUSAL)
     check_mixed_layer_groups(long_tailed, masks.chunked(2048),
                              masks.sliding_window(512))
+
+
+def main():
+    full = grid() == "full"
+    print("single-mask schedules vs dense oracle (fwd + grad):")
+    # W=1000: not a multiple of the 256 block — window cuts mid-block
+    small = [2048, 1024, 512, 300, 212]
+    s_swa = check_single_mask(small, masks.sliding_window(1000), tpw=512,
+                              seed=11)
+    s_causal = check_single_mask(small, masks.CAUSAL, tpw=512, seed=11)
+    # the pruning property, end-to-end on identical batches
+    assert len(s_swa.comm_edges) < len(s_causal.comm_edges), \
+        (len(s_swa.comm_edges), len(s_causal.comm_edges))
+    print(f"  swa ships {len(s_swa.comm_edges)} comm edges < causal "
+          f"{len(s_causal.comm_edges)}  OK")
+    if full:
+        check_full_grid()
     print("ALL MASKED EXECUTOR CASES PASSED")
     return 0
 

@@ -29,7 +29,8 @@ locks that down:
   move are the same plan shipping the same f32 payloads.
 
 Usage: XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-       PYTHONPATH=src python tests/multidevice/run_overlap_executor.py
+       PYTHONPATH=src python tests/multidevice/run_overlap_executor.py \
+       [tier1|full]
 """
 
 import os
@@ -43,56 +44,12 @@ import jax.numpy as jnp                                         # noqa: E402
 import numpy as np                                              # noqa: E402
 
 from repro import masks                                         # noqa: E402
-from repro.core import executor, make_schedule                  # noqa: E402
-from repro.kernels import ref                                   # noqa: E402
+from repro.core import executor                                 # noqa: E402
+from _common import (build, exec_fn, grid, out_loss_grads,      # noqa: E402
+                     ref_fn, rel_err)
 
 ORACLE_TOL = 1e-6          # overlap + f32 wire vs dense oracle
 DKDV_TOL = 1e-6            # dk/dv association-order drift, normalized
-
-
-def rel_err(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return np.abs(a - b).max() / max(1.0, np.abs(b).max())
-
-
-def build(seqlens, n_workers, tpw, bs, hq, kh, d, mask, *, coalesce,
-          overlap, seed=0):
-    sched = make_schedule(seqlens, n_workers, tpw, bs, n_q_heads=hq,
-                          n_kv_heads=kh, head_dim=d, mask=mask,
-                          coalesce=coalesce, wire="f32", overlap=overlap)
-    assert sched.spec.overlap == overlap
-    rng = np.random.default_rng(seed)
-    total = sched.batch.n_tokens
-    mk = lambda h_: jnp.asarray(rng.normal(size=(total, h_, d)),  # noqa: E731
-                                jnp.float32)
-    return sched, mk(hq), mk(kh), mk(kh), mk(hq)
-
-
-def exec_fn(sched, mesh, tpw, impl="xla"):
-    tables = executor.schedule_tables(sched)
-    cfg = executor.ExecConfig(impl=impl)
-
-    def fcp(q, k, v):
-        total = q.shape[0]
-        F = total // tpw
-
-        def sh(x):
-            return x.reshape(F, tpw, x.shape[-2], x.shape[-1])
-
-        o = executor.fcp_attention(sh(q), sh(k), sh(v), tables,
-                                   spec=sched.spec, mesh=mesh,
-                                   cp_axis="data", head_axis=None, cfg=cfg)
-        return o.reshape(total, q.shape[-2], q.shape[-1])
-    return fcp
-
-
-def out_loss_grads(fn, q, k, v, key):
-    def loss(q, k, v):
-        return jnp.sum(fn(q, k, v).astype(jnp.float32) * key)
-
-    o = np.asarray(jax.jit(fn)(q, k, v))
-    ls, g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
-    return o, np.asarray(ls), [np.asarray(x) for x in g]
 
 
 # --------------------------------------------------------------------------
@@ -106,17 +63,13 @@ def check_overlap_equivalence(seqlens, mask, coalesce, impl="xla",
     runs = {}
     for overlap in (False, True):
         sched, q, k, v, key = build(seqlens, n_workers, tpw, bs, hq, kh,
-                                    d, mask, coalesce=coalesce,
-                                    overlap=overlap, seed=seed)
-        runs[overlap] = out_loss_grads(exec_fn(sched, mesh, tpw, impl),
-                                       q, k, v, key)
+                                    d, seed=seed, mask=mask,
+                                    coalesce=coalesce, overlap=overlap)
+        assert sched.spec.overlap == overlap
+        runs[overlap] = out_loss_grads(
+            exec_fn(sched, mesh, tpw, impl=impl), q, k, v, key)
         if not overlap:
-            seg = jnp.asarray(sched.batch.seg_ids)
-            pos = jnp.asarray(sched.batch.positions)
-            o_ref, _ = ref.reference_attention(
-                q.transpose(1, 0, 2), k.transpose(1, 0, 2),
-                v.transpose(1, 0, 2), seg, pos, seg, pos, mask)
-            oerr = rel_err(runs[overlap][0], o_ref.transpose(1, 0, 2))
+            oerr = rel_err(runs[overlap][0], ref_fn(sched, mask)(q, k, v))
             assert oerr < ORACLE_TOL, f"vs oracle: {oerr:.2e}"
 
     o0, l0, (dq0, dk0, dv0) = runs[False]
@@ -140,8 +93,8 @@ def check_overlap_equivalence(seqlens, mask, coalesce, impl="xla",
 
 def check_reshuffle_roundtrip(seqlens, mask, n_workers=8, tpw=512,
                               bs=128, seed=4):
-    sched, *_ = build(seqlens, n_workers, tpw, bs, 2, 1, 16, mask,
-                      coalesce=4, overlap=False, seed=seed)
+    sched, *_ = build(seqlens, n_workers, tpw, bs, 2, 1, 16, seed=seed,
+                      mask=mask, coalesce=4)
     mesh = jax.make_mesh((n_workers,), ("data",))
     tables = executor.schedule_tables(sched)
     rng = np.random.default_rng(seed)
@@ -173,8 +126,7 @@ def check_sched_layout_attention(seqlens, mask, n_workers=8, tpw=512,
     """reshuffle -> layout='sched' attention -> reverse reshuffle must
     be bitwise the ordinary layout='stream' call."""
     sched, q, k, v, key = build(seqlens, n_workers, tpw, bs, hq, kh, d,
-                                mask, coalesce=4, overlap=False,
-                                seed=seed)
+                                seed=seed, mask=mask, coalesce=4)
     mesh = jax.make_mesh((n_workers,), ("data",))
     tables = executor.schedule_tables(sched)
     spec = sched.spec
@@ -209,12 +161,9 @@ def check_sched_layout_attention(seqlens, mask, n_workers=8, tpw=512,
           f"bitwise  OK")
 
 
-def main():
-    long_tailed = [1536, 1024, 512, 300, 212, 512]
+def check_full_grid(long_tailed):
     swa = masks.sliding_window(600)
-
-    print("overlap on/off equivalence (outputs + loss + grads):")
-    for coalesce in (1, 4, 16):
+    for coalesce in (1, 16):
         check_overlap_equivalence(long_tailed, masks.CAUSAL, coalesce,
                                   impl="xla", seed=coalesce)
     check_overlap_equivalence(long_tailed, swa, 4, impl="xla", seed=7)
@@ -222,11 +171,20 @@ def main():
                               impl="fused_xla", seed=8)
 
     print("layer-pipelined reshuffle:")
-    check_reshuffle_roundtrip(long_tailed, masks.CAUSAL)
     check_reshuffle_roundtrip(long_tailed, swa)
     check_sched_layout_attention(long_tailed, masks.CAUSAL)
     check_sched_layout_attention(long_tailed, swa)
 
+
+def main():
+    full = grid() == "full"
+    long_tailed = [1536, 1024, 512, 300, 212, 512]
+    print("overlap on/off equivalence (outputs + loss + grads):")
+    check_overlap_equivalence(long_tailed, masks.CAUSAL, 4, impl="xla",
+                              bs=256, seed=4)
+    check_reshuffle_roundtrip(long_tailed, masks.CAUSAL)
+    if full:
+        check_full_grid(long_tailed)
     print("ALL OVERLAP EXECUTOR CASES PASSED")
     return 0
 

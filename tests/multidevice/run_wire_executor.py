@@ -17,7 +17,8 @@ of O — while kernels and merge math stay exact.  This suite locks down:
   (previously had zero direct coverage).
 
 Usage: XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-       PYTHONPATH=src python tests/multidevice/run_wire_executor.py
+       PYTHONPATH=src python tests/multidevice/run_wire_executor.py \
+       [tier1|full]
 """
 
 import os
@@ -33,18 +34,13 @@ from jax import shard_map                                       # noqa: E402
 from jax.sharding import PartitionSpec as P                     # noqa: E402
 
 from repro import masks                                         # noqa: E402
-from repro.core import executor, make_schedule                  # noqa: E402
-from repro.kernels import ref                                   # noqa: E402
 from repro.runtime import wire                                  # noqa: E402
+from _common import (build, exec_fn, grid, out_loss_grads,      # noqa: E402
+                     ref_fn, rel_err)
 
 ORACLE_TOL = 1e-6          # f32 wire vs dense oracle, normalized
 WIRE_TOL = {"bf16": 1e-2, "int8": 3e-2}     # quantized vs f32 wire
 OUT_BF16_TOL = 1e-2        # restore-cast path vs f32 restore
-
-
-def rel_err(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return np.abs(a - b).max() / max(1.0, np.abs(b).max())
 
 
 # --------------------------------------------------------------------------
@@ -79,76 +75,26 @@ def check_ship_f32_bit_exact(n_workers=8):
 # executor equivalence across wire formats
 # --------------------------------------------------------------------------
 
-def build(seqlens, n_workers, tpw, bs, hq, kh, d, mask, wire_fmt,
-          coalesce=4, seed=0):
-    sched = make_schedule(seqlens, n_workers, tpw, bs, n_q_heads=hq,
-                          n_kv_heads=kh, head_dim=d, mask=mask,
-                          coalesce=coalesce, wire=wire_fmt)
-    rng = np.random.default_rng(seed)
-    total = sched.batch.n_tokens
-    mk = lambda h_: jnp.asarray(rng.normal(size=(total, h_, d)),  # noqa: E731
-                                jnp.float32)
-    return sched, mk(hq), mk(kh), mk(kh), mk(hq)
-
-
-def exec_fn(sched, mesh, tpw, impl="xla", out_dtype=None):
-    tables = executor.schedule_tables(sched)
-    cfg = executor.ExecConfig(impl=impl, out_dtype=out_dtype)
-
-    def fcp(q, k, v):
-        total = q.shape[0]
-        F = total // tpw
-
-        def sh(x):
-            return x.reshape(F, tpw, x.shape[-2], x.shape[-1])
-
-        o = executor.fcp_attention(sh(q), sh(k), sh(v), tables,
-                                   spec=sched.spec, mesh=mesh,
-                                   cp_axis="data", head_axis=None, cfg=cfg)
-        return o.reshape(total, q.shape[-2], q.shape[-1])
-    return fcp
-
-
-def ref_fn(sched, mask):
-    seg = jnp.asarray(sched.batch.seg_ids)
-    pos = jnp.asarray(sched.batch.positions)
-
-    def dense(q, k, v):
-        o, _ = ref.reference_attention(
-            q.transpose(1, 0, 2), k.transpose(1, 0, 2),
-            v.transpose(1, 0, 2), seg, pos, seg, pos, mask)
-        return o.transpose(1, 0, 2)
-    return dense
-
-
-def out_and_grads(fn, q, k, v, key):
-    def loss(q, k, v):
-        return jnp.sum(fn(q, k, v).astype(jnp.float32) * key)
-
-    o = jax.jit(fn)(q, k, v)
-    g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
-    return np.asarray(o, np.float64), [np.asarray(x) for x in g]
-
-
 def check_wire_formats(seqlens, mask, impl="xla", n_workers=8, tpw=512,
                        bs=128, hq=4, kh=2, d=32, seed=0):
     mesh = jax.make_mesh((n_workers,), ("data",))
     runs = {}
     for fmt in ("f32", "bf16", "int8"):
         sched, q, k, v, key = build(seqlens, n_workers, tpw, bs, hq, kh,
-                                    d, mask, fmt, seed=seed)
+                                    d, seed=seed, mask=mask, coalesce=4,
+                                    wire=fmt)
         assert str(sched.spec.wire) == fmt
-        runs[fmt] = out_and_grads(exec_fn(sched, mesh, tpw, impl=impl),
-                                  q, k, v, key)
+        runs[fmt] = out_loss_grads(exec_fn(sched, mesh, tpw, impl=impl),
+                                   q, k, v, key)
         if fmt == "f32":
             # the exact wire still reproduces the dense oracle
             o_ref = ref_fn(sched, mask)(q, k, v)
             err = rel_err(runs[fmt][0], o_ref)
             assert err < ORACLE_TOL, f"f32 wire vs oracle: {err:.2e}"
 
-    o32, g32 = runs["f32"]
+    o32, _, g32 = runs["f32"]
     for fmt in ("bf16", "int8"):
-        o, g = runs[fmt]
+        o, _, g = runs[fmt]
         err = rel_err(o, o32)
         gerr = max(rel_err(a, b) for a, b in zip(g, g32))
         tol = WIRE_TOL[fmt]
@@ -167,9 +113,10 @@ def check_mixed_layer_groups(seqlens, mask_a, mask_b, n_workers=8,
 
     def chain_fn(fmt):
         sched_a, q, k, v, key = build(seqlens, n_workers, tpw, bs, hq,
-                                      kh, d, mask_a, fmt, seed=seed)
+                                      kh, d, seed=seed, mask=mask_a,
+                                      coalesce=4, wire=fmt)
         sched_b, *_ = build(seqlens, n_workers, tpw, bs, hq, kh, d,
-                            mask_b, fmt, seed=seed)
+                            seed=seed, mask=mask_b, coalesce=4, wire=fmt)
         fcp_a = exec_fn(sched_a, mesh, tpw)
         fcp_b = exec_fn(sched_b, mesh, tpw)
 
@@ -184,10 +131,10 @@ def check_mixed_layer_groups(seqlens, mask_a, mask_b, n_workers=8,
     runs = {}
     for fmt in ("f32", "bf16", "int8"):
         f, q, k, v, key = chain_fn(fmt)
-        runs[fmt] = out_and_grads(f, q, k, v, key)
-    o32, g32 = runs["f32"]
+        runs[fmt] = out_loss_grads(f, q, k, v, key)
+    o32, _, g32 = runs["f32"]
     for fmt in ("bf16", "int8"):
-        o, g = runs[fmt]
+        o, _, g = runs[fmt]
         err = rel_err(o, o32)
         gerr = max(rel_err(a, b) for a, b in zip(g, g32))
         # two quantized hops in sequence: errors compound ~2x
@@ -205,10 +152,11 @@ def check_mixed_layer_groups(seqlens, mask_a, mask_b, n_workers=8,
 def check_out_bf16_parity(seqlens, n_workers=8, tpw=512, bs=128, hq=4,
                           kh=2, d=32, seed=9):
     sched, q, k, v, key = build(seqlens, n_workers, tpw, bs, hq, kh, d,
-                                masks.CAUSAL, "f32", seed=seed)
+                                seed=seed, mask=masks.CAUSAL, coalesce=4,
+                                wire="f32")
     mesh = jax.make_mesh((n_workers,), ("data",))
-    o32, g32 = out_and_grads(exec_fn(sched, mesh, tpw), q, k, v, key)
-    obf, gbf = out_and_grads(
+    o32, _, g32 = out_loss_grads(exec_fn(sched, mesh, tpw), q, k, v, key)
+    obf, _, gbf = out_loss_grads(
         exec_fn(sched, mesh, tpw, out_dtype="bfloat16"), q, k, v, key)
     err = rel_err(obf, o32)
     gerr = max(rel_err(a, b) for a, b in zip(gbf, g32))
@@ -219,13 +167,7 @@ def check_out_bf16_parity(seqlens, n_workers=8, tpw=512, bs=128, hq=4,
           f"grad {gerr:.2e}  (tol {OUT_BF16_TOL:.0e})  OK")
 
 
-def main():
-    long_tailed = [1536, 1024, 512, 300, 212, 512]
-    print("ship primitive:")
-    check_ship_f32_bit_exact()
-
-    print("executor wire-format equivalence (outputs + grads):")
-    check_wire_formats(long_tailed, masks.CAUSAL, impl="xla", seed=1)
+def check_full_grid(long_tailed):
     check_wire_formats(long_tailed, masks.sliding_window(600),
                        impl="xla", seed=2)
     check_wire_formats(long_tailed, masks.CAUSAL, impl="fused_xla",
@@ -238,6 +180,18 @@ def main():
     print("restore-cast path:")
     check_out_bf16_parity(long_tailed)
 
+
+def main():
+    full = grid() == "full"
+    long_tailed = [1536, 1024, 512, 300, 212, 512]
+    print("ship primitive:")
+    check_ship_f32_bit_exact()
+
+    print("executor wire-format equivalence (outputs + grads):")
+    check_wire_formats(long_tailed, masks.CAUSAL, impl="xla", bs=256,
+                       seed=1)
+    if full:
+        check_full_grid(long_tailed)
     print("ALL WIRE EXECUTOR CASES PASSED")
     return 0
 
