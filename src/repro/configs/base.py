@@ -140,7 +140,10 @@ class ParallelConfig:
     block_size: int = 4096        # FCP scheduling block (paper: 4K)
     coalesce: int = 16
     remat: bool = True
-    remat_policy: str = "dots"    # "dots" | "nothing" (§Perf #2)
+    # "dots" saves matmul outputs and, on a one-run FCP layer, the fused
+    # attention o/lse ((slots+1)*H*bs*(D+1)*4 bytes a layer) so the
+    # backward reruns no forward kernel; "nothing" (§Perf #2)
+    remat_policy: str = "dots"
     # executor attention impl: per-step ("xla" | "pallas") or one fused
     # launch per run ("fused_xla" | "fused" — the latter is the
     # schedule-table-driven Pallas kernel, "fused_xla" its CPU fallback)

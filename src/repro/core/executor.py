@@ -274,7 +274,8 @@ def _fcp_local(q, k, v, t, *, spec: StaticSpec, cp_axis: str,
                 qs, kxt, vxt, acc_o, acc_lse, tabs, mask=spec.mask,
                 impl="pallas" if cfg.impl == "fused" else "xla",
                 block_q=cfg.block_q, block_k=cfg.block_k,
-                interpret=cfg.interpret, xla_chunk=cfg.xla_chunk)
+                interpret=cfg.interpret, xla_chunk=cfg.xla_chunk,
+                save_out=spec.n_runs == 1)
         elif hi > lo:
             for step in range(lo, hi):
                 qslot = t["step_q"][step]

@@ -33,6 +33,7 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..masks import CAUSAL, MaskSpec, coerce_mask
 from . import flash_attention as fa
@@ -47,6 +48,7 @@ class KernelConfig:
     block_k: int = fa.DEFAULT_BLOCK_K
     interpret: bool = False
     xla_chunk: int = 512
+    save_out: bool = False          # fused only: see fused_run_attention
 
     def __post_init__(self):
         # interpret mode is the CPU stand-in for the chip; on the chip
@@ -131,6 +133,11 @@ merge_many = ref.merge_many
 #   k_seg_b/k_pos_b [S, bs] per-step kv metadata in bwd order (pallas)
 
 
+# checkpoint name of a fused run's (o, lse) residuals; the "dots" policy
+# of ``models.transformer.apply_remat`` saves values under this name
+FUSED_ATTN_OUT = "fcp_attn_out"
+
+
 def _visited(idx, n: int):
     return jnp.zeros((n,), bool).at[idx].set(True)
 
@@ -155,6 +162,11 @@ def _fused_pallas(cfg: KernelConfig, qs, kxt, vxt, acc_o, acc_lse, tabs):
 
 def _fused_pl_fwd(cfg, qs, kxt, vxt, acc_o, acc_lse, tabs):
     o2, l2 = _fused_pallas(cfg, qs, kxt, vxt, acc_o, acc_lse, tabs)
+    if cfg.save_out:
+        # named on the residuals themselves: a remat policy that saves
+        # the name keeps them, and the backward reruns no forward kernel
+        o2 = checkpoint_name(o2, FUSED_ATTN_OUT)
+        l2 = checkpoint_name(l2, FUSED_ATTN_OUT)
     return (o2, l2), (qs, kxt, vxt, acc_o, acc_lse, o2, l2, tabs)
 
 
@@ -238,18 +250,26 @@ def fused_run_attention(qs, kxt, vxt, acc_o, acc_lse, tabs, *,
                         block_q: int = fa.DEFAULT_BLOCK_Q,
                         block_k: int = fa.DEFAULT_BLOCK_K,
                         interpret: bool = False,
-                        xla_chunk: int = 512):
+                        xla_chunk: int = 512,
+                        save_out: bool = False):
     """Fold one run of schedule steps into the flash accumulators.
 
     qs: [SL, H, bs, D] schedule-layout q; kxt/vxt: [EX, KH, bs, D]
     extended KV buffers; acc_o/acc_lse: [SL, H, bs(, D)] f32.  Returns
     the updated accumulators; slots the run does not visit pass through
     unchanged (so gradients flow across runs).
+
+    ``save_out`` (pallas only) names the run's (o, lse) residuals
+    :data:`FUSED_ATTN_OUT`, which the "dots" remat policy keeps: the
+    backward then launches no second forward.  The executor sets it
+    when the layer has one run; with more, every run would keep its own
+    f32 accumulator.
     """
     mask = coerce_mask(mask)
     if impl == "pallas":
         cfg = KernelConfig(mask=mask, scale=scale, block_q=block_q,
-                           block_k=block_k, interpret=interpret)
+                           block_k=block_k, interpret=interpret,
+                           save_out=save_out)
         return _fused_pallas(cfg, qs, kxt, vxt, acc_o, acc_lse, tabs)
     if impl == "xla":
         return _fused_xla(qs, kxt, vxt, acc_o, acc_lse, tabs,
@@ -260,13 +280,16 @@ def fused_run_attention(qs, kxt, vxt, acc_o, acc_lse, tabs, *,
 def count_attention_launches(fn, *args) -> dict[str, int]:
     """Trace ``fn(*args)`` and count attention-op calls.
 
-    Returns ``{"step": n_block_attention, "fused": n_fused_runs}`` — the
-    per-worker per-layer launch accounting the fused executor is meant to
-    shrink from ``n_steps`` to ``<= n_rounds + 1``.  Tracing (not
-    running) is enough: the executor unrolls its run loop in Python.
+    Returns ``{"step": n_block_attention, "fused": n_fused_runs,
+    "fused_saved": n_saved}`` — the per-worker per-layer launch
+    accounting the fused executor is meant to shrink from ``n_steps`` to
+    ``<= n_rounds + 1``; ``fused_saved`` counts the Pallas fused runs
+    whose output is named for the remat policy to keep (one per one-run
+    layer, none on a multi-run plan).  Tracing (not running) is enough:
+    the executor unrolls its run loop in Python.
     """
     import jax as _jax
-    calls = {"step": 0, "fused": 0}
+    calls = {"step": 0, "fused": 0, "fused_saved": 0}
     orig_b, orig_f = block_attention, fused_run_attention
     mod = sys.modules[__name__]
 
@@ -276,6 +299,8 @@ def count_attention_launches(fn, *args) -> dict[str, int]:
 
     def fused(*a, **kw):
         calls["fused"] += 1
+        if kw.get("save_out") and kw.get("impl") == "pallas":
+            calls["fused_saved"] += 1
         return orig_f(*a, **kw)
 
     mod.block_attention, mod.fused_run_attention = blk, fused

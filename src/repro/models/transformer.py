@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from ..kernels import ops
 from . import layers as L
 from .moe import init_moe_ffn, moe_ffn
 
@@ -131,14 +132,21 @@ def unembed(params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 
 def apply_remat(body, remat):
-    """remat: False | True/'dots' (save matmul outputs) | 'nothing'
-    (recompute everything — minimal activation memory, §Perf #2)."""
+    """remat: False | True/'dots' | 'nothing' (recompute everything —
+    minimal activation memory, §Perf #2).
+
+    'dots' saves matmul outputs and, on a layer whose FCP schedule has
+    one run, the fused attention output (``ops.FUSED_ATTN_OUT``: the
+    f32 ``o`` and ``lse`` accumulators, ``(slots+1)·H·bs·(D+1)·4`` bytes
+    a layer), so the backward launches no second forward kernel."""
     if not remat:
         return body
     if remat == "nothing":
         return jax.checkpoint(body)
-    return jax.checkpoint(
-        body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    cp = jax.checkpoint_policies
+    return jax.checkpoint(body, policy=cp.save_from_both_policies(
+        cp.dots_with_no_batch_dims_saveable,
+        cp.save_only_these_names(ops.FUSED_ATTN_OUT)))
 
 
 def forward(params, cfg: ModelConfig, batch: dict,

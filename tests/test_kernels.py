@@ -13,9 +13,11 @@ try:
 except ImportError:                       # minimal install: skip @given only
     from _hypothesis_fallback import given, settings, st
 
+from _jaxpr import count_kernel_calls
 from repro import masks
 from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
+from repro.models.transformer import apply_remat
 
 
 def _make_inputs(rng, h, kh, sq, sk, d, dtype, n_docs=3, pad_frac=0.1):
@@ -287,6 +289,81 @@ def test_fused_bwd_matches_xla_autodiff():
         else:
             m = live
         np.testing.assert_allclose(a[m], b[m], atol=5e-6, rtol=5e-6,
+                                   err_msg=name)
+
+
+def _run_chain(tabs, meta, n_runs):
+    """``tabs`` cut into ``n_runs`` consecutive runs (each q-slot sorted
+    and with its own kv-sorted backward order), as the executor does."""
+    _, _, kv_seg, kv_pos = meta
+    sq, skv = np.asarray(tabs["step_q"]), np.asarray(tabs["step_kv"])
+    cuts = np.linspace(0, len(sq), n_runs + 1).astype(int)
+    runs = []
+    for lo, hi in zip(cuts[:-1], cuts[1:]):
+        q_, kv_ = sq[lo:hi], skv[lo:hi]
+        order = np.lexsort((q_, kv_))
+        runs.append(dict(
+            step_q=jnp.asarray(q_), step_kv=jnp.asarray(kv_),
+            q_seg=tabs["q_seg"], q_pos=tabs["q_pos"],
+            k_seg=kv_seg[kv_], k_pos=kv_pos[kv_],
+            bwd_q=jnp.asarray(q_[order]), bwd_kv=jnp.asarray(kv_[order]),
+            k_seg_b=kv_seg[kv_[order]], k_pos_b=kv_pos[kv_[order]]))
+    return runs
+
+
+def _remat_chain_loss(rng, policy, n_runs, save_out):
+    """A checkpointed body folding a chain of fused runs, as one layer of
+    the executor does, and its inputs."""
+    qs, kxt, vxt, tabs, acc_o, acc_lse, meta = _fused_setup(rng)
+    runs = _run_chain(tabs, meta, n_runs)
+    key_o = jnp.asarray(rng.normal(size=qs.shape), jnp.float32)
+    key_l = jnp.asarray(rng.normal(size=acc_lse.shape), jnp.float32)
+
+    def body(qs_, k_, v_, o, lse):
+        for t in runs:
+            o, lse = ops.fused_run_attention(
+                qs_, k_, v_, o, lse, t, mask=True, impl="pallas",
+                block_q=64, block_k=64, interpret=True, save_out=save_out)
+        return (jnp.sum(o * key_o)
+                + jnp.sum(jnp.where(lse > -1e29, lse * key_l, 0.0)))
+
+    return apply_remat(body, policy), (qs, kxt, vxt, acc_o, acc_lse)
+
+
+@pytest.mark.parametrize("policy,n_runs,save_out,launches", [
+    ("dots", 1, True, 1),        # the run's (o, lse) kept: no rerun
+    ("dots", 1, False, 2),       # unnamed: the backward reruns it
+    ("nothing", 1, True, 2),     # the name alone keeps nothing
+    ("dots", 2, False, 4),       # multi-run plans: 2 per run, as before
+])
+def test_fused_fwd_launches_under_remat(policy, n_runs, save_out,
+                                        launches):
+    """Forward kernel launches in the grad of a checkpointed body: the
+    "dots" policy keeps a run's named output, so the backward launches
+    no second ``fused_flash_fwd``."""
+    loss, args = _remat_chain_loss(np.random.default_rng(14), policy,
+                                   n_runs, save_out)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*args)
+    assert count_kernel_calls(jaxpr.jaxpr, "_fused_fwd_kernel") == launches
+
+
+def test_fused_saved_output_grads_match_rerun():
+    """Gradients from the saved run output == gradients from the rerun
+    forward (the kernel is deterministic), on live rows."""
+    grads = []
+    for save_out in (True, False):
+        loss, args = _remat_chain_loss(np.random.default_rng(15), "dots",
+                                       1, save_out)
+        grads.append(jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(
+            *args))
+    live = np.asarray(args[4]) > -1e29           # incoming-acc live rows
+    for a, b, name in zip(*grads, ["qs", "kxt", "vxt", "acc_o", "acc_lse"]):
+        a, b = np.asarray(a), np.asarray(b)
+        if name.startswith("acc"):
+            m = np.broadcast_to(live.reshape(live.shape + (1,) * (
+                a.ndim - live.ndim)), a.shape)
+            a, b = a[m], b[m]
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-6,
                                    err_msg=name)
 
 

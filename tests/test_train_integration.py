@@ -177,6 +177,50 @@ def test_train_step_with_mixed_mask_pattern():
     assert np.mean(losses[-3:]) < np.mean(losses[:3])
 
 
+def test_fused_output_saved_on_one_run_plans_only():
+    """The executor names each layer's fused output for the "dots" remat
+    policy on a one-run plan (one worker), so the grad launches one
+    ``fused_flash_fwd`` a layer; a multi-worker plan with comm rounds
+    names none.  Trace only."""
+    from jax.sharding import AbstractMesh
+
+    from _jaxpr import count_kernel_calls
+    from repro.kernels import ops
+
+    cfg, mesh, model, _, _, loader, params, _, _ = _setup()
+    pcfg = ParallelConfig(block_size=256, attention_impl="fused",
+                          attn_interpret=True, attn_block_q=128,
+                          attn_block_k=128)
+    b = loader.next()
+    batch = T.batch_arrays(b, cfg)
+    sched = T.build_schedule(cfg, pcfg, b.seqlens, 1, 2048)
+    assert sched.spec.n_runs == 1
+    attn = (T.make_fcp_attn_fn(sched, mesh, pcfg),) * cfg.n_layers
+
+    def loss(p):
+        return model.loss(p, batch, attn, remat="dots")
+
+    c = ops.count_attention_launches(loss, params)
+    assert c["fused"] == c["fused_saved"] == cfg.n_layers, c
+    grad = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+    assert count_kernel_calls(grad, "_fused_fwd_kernel") == cfg.n_layers
+
+    n = 4
+    b4 = SyntheticLoader(dist="real_world", n_frames=n,
+                         tokens_per_worker=1024, vocab_size=cfg.vocab_size,
+                         seed=0, bucket_min_len=256).next()
+    sched4 = T.build_schedule(cfg, pcfg, b4.seqlens, n, 1024)
+    assert sched4.spec.n_rounds > 0
+    attn4 = T.make_fcp_attn_fn(
+        sched4, AbstractMesh((n, 1), ("data", "model")), pcfg)
+    q = jax.ShapeDtypeStruct((n, 1024, cfg.n_heads, cfg.head_dim),
+                             jnp.float32)
+    kv = jax.ShapeDtypeStruct((n, 1024, cfg.n_kv_heads, cfg.head_dim),
+                              jnp.float32)
+    c4 = ops.count_attention_launches(attn4, q, kv, kv)
+    assert c4["fused"] > 1 and c4["fused_saved"] == 0, c4
+
+
 def _restore_compile_cache():
     from jax.experimental.compilation_cache import compilation_cache
     jax.config.update("jax_compilation_cache_dir", None)
