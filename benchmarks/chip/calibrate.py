@@ -10,7 +10,9 @@ For each seed: the program's first three steps through the timed path
 control (the reference computed with float8 matmuls, put in the
 program's place), the half-batch fault (the reference with the second
 half of the tokens left out of the loss) and the altered-loss fault
-(the reference reporting each loss 1% high) against the reference.
+(the reference reporting each loss 1% high) against the reference, and
+where the cell has several workers the exchange left out (the reference
+with attention cut at the workers' frames).
 A state left unchanged reads 1 on both norm gaps by construction and
 is not run.  One JSON line per reading goes to ``--out`` and to
 standard output.
@@ -43,14 +45,13 @@ def main(argv=None) -> None:
     import jax
 
     from chipbench import compare, harness, reference, registry
-    from chipbench.model_cfg import Dims
 
     cell = registry.cell(args.workload, ROOT)
     harness.check_devices(cell.chips, require_chip=True)
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    dims = Dims.from_config(cell.config)
+    dims = cell.arch.sizes(cell.config)
     hp = reference.Hyper.from_traffic(cell.traffic)
     out = open(args.out, "a") if args.out else None
 
@@ -65,24 +66,26 @@ def main(argv=None) -> None:
         t0 = time.perf_counter()
         drv = registry.driver(cell.traffic["driver"]).Driver(
             cell, dims, seed, hp)
-        first, prog = harness.warm_up(drv, dims, hp, seed)
+        first, prog = harness.warm_up(drv, cell.arch, dims, hp, seed)
         checked = [(s.tokens, s.seqlens)
                    for s in first[:harness.CHECK_STEPS]]
         drv.free()
         del drv
         t1 = time.perf_counter()
-        ref = reference.run(dims, hp, seed, checked)
+        ref = cell.arch.run(dims, hp, seed, checked)
         t2 = time.perf_counter()
         emit({"cell": cell.name, "seed": seed, "kind": "program",
               "numbers": compare.numbers(prog, ref),
               "losses": prog.losses, "ref_losses": ref.losses,
               "setup_s": t1 - t0, "reference_s": t2 - t1})
         if i < args.controls:
-            for kind, kw in (("control_fp8", {"mode": "fp8"}),
-                             ("fault_half_batch", {"fault": "half_batch"}),
-                             ("fault_loss_altered",
-                              {"fault": "loss_altered"})):
-                other = reference.run(dims, hp, seed, checked, **kw)
+            kinds = [("control_fp8", {"mode": "fp8"}),
+                     ("fault_half_batch", {"fault": "half_batch"}),
+                     ("fault_loss_altered", {"fault": "loss_altered"})]
+            if int(cell.traffic["workers"]) > 1:
+                kinds.append(("fault_no_exchange", {"fault": "no_exchange"}))
+            for kind, kw in kinds:
+                other = cell.arch.run(dims, hp, seed, checked, **kw)
                 emit({"cell": cell.name, "seed": seed, "kind": kind,
                       "numbers": compare.numbers(other, ref),
                       "losses": other.losses})

@@ -7,7 +7,9 @@ same call the window makes, so every program the window uses is
 compiled (or loaded from the compile cache) before it opens.  The first
 three of those steps are the ones the reference follows.  The window
 then trains whole passes over the compositions until ``seconds`` have
-passed.  Once it has closed and the device's peak memory is read, the
+passed.  A traced run records the window's first ``trace_passes``
+passes, where the traffic names that many, and all of it otherwise.
+Once the window has closed and the device's peak memory is read, the
 program's state is dropped and the reference runs the first three
 steps again from the seed.
 """
@@ -24,8 +26,6 @@ import time
 import numpy as np
 
 from . import compare, flops, reference, registry, trace_reduce
-from .model_cfg import Dims
-from .weights import maker, seed_key
 
 CHECK_STEPS = 3
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -35,13 +35,14 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 class Run:
     """What the metric readers read."""
     cell: registry.Cell
-    dims: Dims
+    dims: object                # the sizes of ``cell.arch``
     peak: dict
     chips: int
     tokens_per_step: int
     setup_s: float
     window_s: float
     steps: list                 # seqlens of every step in the window
+                                # (of every traced step, where traced)
     step_s: list                # host seconds of each step, to its sync
     compiles_in_window: int
     memory_peak_bytes: int | None
@@ -89,32 +90,57 @@ def memory_peak(chips: int) -> int | None:
     return max(peaks) if peaks else None
 
 
-def warm_up(drv, dims, hp, seed: int):
+def warm_up(drv, arch, dims, hp, seed: int):
     """One step per composition.  Returns the step records and the
     program's readings: the first three losses, the first gradient's
     norms (read after the first step) and the change of the weights
-    (read after the third)."""
+    (read after the third, against the seed's weights drawn again by
+    ``arch`` where the program holds its own)."""
+    import jax
+
     records, grads, change = [], None, None
     for i in range(max(len(drv.compositions), CHECK_STEPS)):
         records.append(drv.step())
         if i == 0:
             grads = reference.leaf_norms(drv.opt.m, 1.0 / (1.0 - hp.b1))
         if i == CHECK_STEPS - 1:
-            change = reference.change_norms(drv.params,
-                                            maker(dims)(seed_key(seed)))
+            where = jax.tree.map(lambda a: a.sharding, drv.params)
+            change = reference.change_norms(
+                drv.params, arch.maker(dims, out_shardings=where)(
+                    reference.seed_key(seed)))
     prog = reference.Readings(
         losses=[r.loss for r in records[:CHECK_STEPS]], grad_norms=grads,
         update_norms=change)
     return records, prog
 
 
-def window(drv, seconds: float):
-    """Whole passes over the compositions until ``seconds`` have passed."""
+def window(drv, seconds: float, trace_dir: str | None = None,
+           trace_passes: int = 0):
+    """Whole passes over the compositions until ``seconds`` have passed.
+    With ``trace_dir`` the profiler writes its trace of the first
+    ``trace_passes`` passes there (of the whole window where 0); the
+    ``bench.window`` span marks the traced part.  Returns the steps,
+    their host seconds, the window's seconds, the programs obtained
+    inside it and the number of steps traced."""
+    import jax
     from jax.profiler import TraceAnnotation
 
     steps, step_s = [], []
-    with CompileCounter() as counter, \
-            TraceAnnotation(trace_reduce.WINDOW_SPAN):
+    traced = None               # steps traced, once the span has closed
+
+    def close_span():
+        nonlocal traced
+        span.__exit__(None, None, None)
+        traced = len(steps)
+        if trace_dir is not None:
+            jax.profiler.stop_trace()
+
+    if trace_dir is not None:
+        jax.profiler.start_trace(trace_dir)
+    # made once the profiler runs: a span made before it records nothing
+    span = TraceAnnotation(trace_reduce.WINDOW_SPAN)
+    span.__enter__()
+    with CompileCounter() as counter:
         t0 = t_prev = time.perf_counter()
         while t_prev - t0 < seconds:
             for _ in drv.compositions:
@@ -122,7 +148,13 @@ def window(drv, seconds: float):
                 t = time.perf_counter()
                 step_s.append(t - t_prev)
                 t_prev = t
-    return steps, step_s, t_prev - t0, counter.n
+            if (traced is None and trace_dir is not None
+                    and len(steps) == trace_passes * len(drv.compositions)):
+                close_span()
+        window_s = t_prev - t0
+        if traced is None:
+            close_span()
+    return steps, step_s, window_s, counter.n, traced
 
 
 def run_cell(cell: registry.Cell, seed: int, seconds: float, trace: bool,
@@ -142,36 +174,35 @@ def run_cell(cell: registry.Cell, seed: int, seconds: float, trace: bool,
         enable_compile_cache()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
-    dims = Dims.from_config(cell.config)
+    dims = cell.arch.sizes(cell.config)
     hp = reference.Hyper.from_traffic(cell.traffic)
     drv = registry.driver(cell.traffic["driver"], bench_dir).Driver(
         cell, dims, seed, hp)
-    first, prog = warm_up(drv, dims, hp, seed)
+    first, prog = warm_up(drv, cell.arch, dims, hp, seed)
     checked = [(s.tokens, s.seqlens) for s in first[:CHECK_STEPS]]
     setup_s = time.perf_counter() - t_start
 
     trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace \
         else None
-    if trace:
-        jax.profiler.start_trace(trace_dir)
-    steps, step_s, window_s, compiles = window(drv, seconds)
+    steps, step_s, window_s, compiles, traced = window(
+        drv, seconds, trace_dir, int(cell.traffic.get("trace_passes", 0)))
     view = None
     if trace:
-        jax.profiler.stop_trace()
         view = trace_reduce.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
     mem = memory_peak(cell.chips)
 
     drv.free()
     del drv
-    ref = reference.run(dims, hp, seed, checked)
+    ref = cell.arch.run(dims, hp, seed, checked)
     ok, compared = compare.judge(compare.numbers(prog, ref), cell.limits)
 
     run = Run(cell=cell, dims=dims, peak=peak, chips=cell.chips,
               tokens_per_step=int(cell.traffic["workers"])
               * int(cell.traffic["tokens_per_worker"]),
               setup_s=setup_s, window_s=window_s,
-              steps=[s.seqlens for s in steps], step_s=step_s,
+              steps=[s.seqlens for s in steps[:traced]],
+              step_s=step_s[:traced],
               compiles_in_window=compiles,
               memory_peak_bytes=mem, trace=view)
     metrics = {}
@@ -190,7 +221,8 @@ def run_cell(cell: registry.Cell, seed: int, seconds: float, trace: bool,
         out["breakdown"] = {"device_ops": trace_reduce.top_ops(view),
                             "idle_gaps": trace_reduce.idle_gaps(view)}
     out["compared"] = compared
-    print(f"window: {len(steps)} steps in {window_s:.3f} s, "
+    print(f"window: {len(steps)} steps in {window_s:.3f} s"
+          f"{f' ({traced} traced)' if trace else ''}, "
           f"{compiles} program(s) obtained inside it; steps (ms) "
           f"{[round(t * 1e3, 1) for t in step_s]}", file=sys.stderr)
     print(f"losses {prog.losses} (reference {ref.losses})",

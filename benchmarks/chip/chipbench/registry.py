@@ -3,9 +3,11 @@
 Nothing here knows a particular cell.  A cell names a configuration (a
 file that the configuration's entry points to) and a traffic mix
 (``traffic/<name>.json``); the traffic names its driver
-(``drivers/<name>.py``); a metric is ``metrics/<name>.py``; the limits
-that decide ``correct`` are ``limits/<cell>.json``.  A new cell or
-metric is new files and new entries, never an edit."""
+(``drivers/<name>.py``); the configuration names its architecture
+(``chipbench/archs/<name>.py``, by its ``"arch"`` key, ``dense`` where it
+has none); a metric is ``metrics/<name>.py``; the limits that decide
+``correct`` are ``limits/<cell>.json``.  A new cell, metric or
+architecture is new files and new entries, never an edit."""
 
 from __future__ import annotations
 
@@ -26,6 +28,7 @@ class Cell:
     chips: int
     config_name: str
     config: dict            # the configuration file, as run
+    arch: types.ModuleType  # chipbench/archs/<config's "arch">.py
     traffic_name: str
     traffic: dict
     limits: dict
@@ -54,9 +57,10 @@ def cell(name: str, root: pathlib.Path = ROOT,
                        f"{sorted(by_name)}")
     w = by_name[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    config = json.loads((root / conf["file"]).read_text())
     return Cell(
         name=name, chips=int(w["chips"]), config_name=conf["name"],
-        config=json.loads((root / conf["file"]).read_text()),
+        config=config, arch=arch(config.get("arch", "dense"), bench_dir),
         traffic_name=w["traffic"],
         traffic=json.loads(
             (bench_dir / "traffic" / f"{w['traffic']}.json").read_text()),
@@ -84,9 +88,16 @@ def metric(name: str, bench_dir: pathlib.Path = BENCH_DIR
     return _module(pathlib.Path(bench_dir) / "metrics" / f"{name}.py")
 
 
+def arch(name: str, bench_dir: pathlib.Path = BENCH_DIR
+         ) -> types.ModuleType:
+    """``chipbench/archs/<name>.py``: what :mod:`chipbench.archs` lists."""
+    return _module(pathlib.Path(bench_dir) / "chipbench" / "archs"
+                   / f"{name}.py")
+
+
 def driver(name: str, bench_dir: pathlib.Path = BENCH_DIR
            ) -> types.ModuleType:
     """``drivers/<name>.py``: exposes ``Driver(cell, dims, seed, hp)``
-    with ``step()``, ``compositions``, ``params``, ``opt`` and
-    ``free()``."""
+    (``dims`` the architecture's sizes) with ``step()``,
+    ``compositions``, ``params``, ``opt`` and ``free()``."""
     return _module(pathlib.Path(bench_dir) / "drivers" / f"{name}.py")

@@ -146,6 +146,44 @@ def matching_s(view: TraceView, match: Callable[[Event], bool]) -> float:
     return sum(tot) / len(tot) * 1e-9
 
 
+def _overlap_ns(a: list[tuple], b: list[tuple]) -> float:
+    """Length of the intersection of two merged interval lists."""
+    i = j = 0
+    tot = 0.0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            tot += hi - lo
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return tot
+
+
+def exposed_s(view: TraceView, match: Callable[[Event], bool]) -> float:
+    """Device seconds of the ops that ``match`` selects during which no
+    other op ran on the same device, within the window, averaged over
+    the devices: the part of that work nothing hides."""
+    if not view.devices:
+        return 0.0
+    tot = []
+    for ops in view.devices.values():
+        mine = merge(_clipped([e for e in ops if match(e)], view.window))
+        rest = merge(_clipped([e for e in ops if not match(e)],
+                              view.window))
+        tot.append(sum(b - a for a, b in mine) - _overlap_ns(mine, rest))
+    return sum(tot) / len(tot) * 1e-9
+
+
+def prefix_matcher(names: Iterable[str]) -> Callable[[Event], bool]:
+    """Selects the ops whose instruction name starts with one of
+    ``names`` (``collective-permute`` matches
+    ``collective-permute-start.3``)."""
+    names = tuple(names)
+    return lambda e: e.name.startswith(names)
+
+
 def names_matcher(names: Iterable[str]) -> Callable[[Event], bool]:
     """Selects the ops whose instruction name is one of ``names`` with
     XLA's numeric suffix (``fused_flash_fwd`` matches

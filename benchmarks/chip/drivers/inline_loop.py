@@ -8,9 +8,11 @@ loss.  The calls and their order are ``main``'s; this copy goes once
 that loop is a function of the program's own.
 
 What the benchmark supplies instead of ``main``: the weights (drawn by
-:mod:`chipbench.weights` from the seed, in their train shardings), the
-loader's seed (the run's), and the loader's bounded set of compositions
-(the traffic file's, in the order the seed gives them).
+the cell's architecture, ``cell.arch.maker``, from the seed, in their
+train shardings), the loader's seed (the run's), and the loader's
+bounded set of compositions (the traffic file's, in the order the seed
+gives them).  :func:`program_configs` and :func:`seeded_state` are
+shared with the other drivers of a dense cell.
 
 Each phase of a step runs inside a host span (``bench.loader``,
 ``bench.batch``, ``bench.plan``, ``bench.build``, ``bench.dispatch``,
@@ -27,7 +29,7 @@ import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from chipbench import traffic_gen, weights
+from chipbench import reference, traffic_gen
 
 
 @dataclasses.dataclass
@@ -37,35 +39,60 @@ class Step:
     loss: float
 
 
+def program_configs(cell, dims, seed: int, hp):
+    """The program's model, parallel and train configurations of a dense
+    cell: the sizes as run, the traffic's ``parallel`` block, the
+    traffic's hyper-parameters and the run's seed."""
+    from repro.configs.base import ModelConfig, ParallelConfig, TrainConfig
+    from repro.launch import train
+
+    cfg = ModelConfig(
+        name=cell.config_name, family="dense", n_layers=dims.n_layers,
+        d_model=dims.d_model, n_heads=dims.n_heads,
+        n_kv_heads=dims.n_kv_heads, d_ff=dims.d_ff,
+        vocab_size=dims.vocab, head_dim=dims.head_dim,
+        qkv_bias=dims.qkv_bias, rope_theta=dims.rope_theta,
+        norm_eps=dims.norm_eps, param_dtype=dims.param_dtype)
+    pcfg = ParallelConfig(
+        block_size=int(cell.traffic["block_size"]),
+        in_dtype_bytes=train._param_dtype_bytes(cfg),
+        **cell.traffic.get("parallel", {}))
+    tcfg = TrainConfig(
+        lr=hp.lr, warmup_steps=hp.warmup_steps,
+        total_steps=hp.total_steps, weight_decay=hp.weight_decay,
+        grad_clip=hp.grad_clip, b1=hp.b1, b2=hp.b2, seed=int(seed))
+    return cfg, pcfg, tcfg
+
+
+def seeded_state(cell, dims, seed: int, mesh):
+    """Weights from the seed and fresh AdamW state, each made on the
+    device in one jitted call, in the trainer's shardings on ``mesh``."""
+    from repro.launch import train
+    from repro.optimizer import adamw
+
+    make = cell.arch.maker(dims)
+    key = reference.seed_key(seed)
+    p_like = jax.eval_shape(make, key)
+    o_like = jax.eval_shape(adamw.init, p_like)
+    psh, osh = train._state_shardings(p_like, o_like, mesh)
+    params = cell.arch.maker(dims, out_shardings=psh)(key)
+    return params, jax.jit(adamw.init, out_shardings=osh)(params)
+
+
 class Driver:
     def __init__(self, cell, dims, seed: int, hp):
-        from repro.configs.base import ModelConfig, ParallelConfig, TrainConfig
         from repro.core import plan_cache as pc
         from repro.data.loader import SyntheticLoader
         from repro.launch import train
         from repro.launch.mesh import make_mesh
         from repro.models import Model
-        from repro.optimizer import adamw
 
         self.train = train
         tr = cell.traffic
         n_cp, tpw = int(tr["workers"]), int(tr["tokens_per_worker"])
         self.n_cp, self.tpw = n_cp, tpw
-        self.cfg = ModelConfig(
-            name=cell.config_name, family="dense", n_layers=dims.n_layers,
-            d_model=dims.d_model, n_heads=dims.n_heads,
-            n_kv_heads=dims.n_kv_heads, d_ff=dims.d_ff,
-            vocab_size=dims.vocab, head_dim=dims.head_dim,
-            qkv_bias=dims.qkv_bias, rope_theta=dims.rope_theta,
-            norm_eps=dims.norm_eps, param_dtype=dims.param_dtype)
-        self.pcfg = ParallelConfig(
-            block_size=int(tr["block_size"]),
-            in_dtype_bytes=train._param_dtype_bytes(self.cfg),
-            **tr.get("parallel", {}))
-        self.tcfg = TrainConfig(
-            lr=hp.lr, warmup_steps=hp.warmup_steps,
-            total_steps=hp.total_steps, weight_decay=hp.weight_decay,
-            grad_clip=hp.grad_clip, b1=hp.b1, b2=hp.b2, seed=int(seed))
+        self.cfg, self.pcfg, self.tcfg = program_configs(cell, dims, seed,
+                                                         hp)
         self.mesh = make_mesh((n_cp, 1), ("data", "model"))
         self.model = Model(self.cfg, tp=1)
 
@@ -78,13 +105,7 @@ class Driver:
             bucket_min_len=self.pcfg.block_size, fresh=False)
         self.loader.compositions = [list(c) for c in self.compositions]
 
-        make = weights.maker(dims)
-        key = weights.seed_key(seed)
-        p_like = jax.eval_shape(make, key)
-        o_like = jax.eval_shape(adamw.init, p_like)
-        psh, osh = train._state_shardings(p_like, o_like, self.mesh)
-        self.params = weights.maker(dims, out_shardings=psh)(key)
-        self.opt = jax.jit(adamw.init, out_shardings=osh)(self.params)
+        self.params, self.opt = seeded_state(cell, dims, seed, self.mesh)
         self.residual = None
 
         self.plan_cache = pc.PlanCache(self.pcfg.plan_cache_size)

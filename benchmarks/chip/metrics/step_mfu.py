@@ -2,7 +2,7 @@
 window's) x chips x peak, in percent: the step's share of the peak
 while the device runs, with host idle taken out."""
 
-from chipbench import flops, trace_reduce
+from chipbench import trace_reduce
 
 
 def read(run):
@@ -11,5 +11,5 @@ def read(run):
     busy = trace_reduce.busy_s(run.trace)
     if busy <= 0:
         return None
-    work = sum(flops.model_flops(run.dims, s) for s in run.steps)
+    work = sum(run.cell.arch.model_flops(run.dims, s) for s in run.steps)
     return 100.0 * work / (busy * run.chips * run.peak["bf16_flops_per_s"])

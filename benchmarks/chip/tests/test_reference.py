@@ -8,8 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from tests import tiny
-from chipbench import reference, traffic_gen, weights
-from chipbench.model_cfg import Dims
+from chipbench import reference, traffic_gen
+from chipbench.archs import dense
 
 
 def test_reference_matches_program_model():
@@ -18,7 +18,7 @@ def test_reference_matches_program_model():
     from repro.launch.mesh import make_mesh
     from repro.models import Model
 
-    dims = Dims.from_config(dict(tiny.CONFIG, param_dtype="float32"))
+    dims = dense.sizes(dict(tiny.CONFIG, param_dtype="float32"))
     cfg = ModelConfig(
         name="tiny", family="dense", n_layers=dims.n_layers,
         d_model=dims.d_model, n_heads=dims.n_heads,
@@ -31,7 +31,7 @@ def test_reference_matches_program_model():
     seqlens = traffic_gen.compositions(tiny.TRAFFIC)[0]
     assert len(seqlens) > 1
     tokens = np.random.default_rng(1).integers(1, dims.vocab, tpw)
-    params = weights.maker(dims)(weights.seed_key(5))
+    params = dense.maker(dims)(reference.seed_key(5))
 
     mesh = make_mesh((1, 1), ("data", "model"))
     sched = train.build_schedule(cfg, pcfg, seqlens, 1, tpw)
@@ -46,8 +46,8 @@ def test_reference_matches_program_model():
         loss, grads = jax.jit(jax.value_and_grad(
             functools.partial(model.loss, batch=batch, attn_fn=attn)))(
                 params)
-    ref_loss, ref_grads = reference.loss_and_grads(dims, params, tokens,
-                                                   seqlens)
+    ref_loss, ref_grads = dense.loss_and_grads(dims, params, tokens,
+                                               seqlens)
     np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
     flat, _ = jax.tree.flatten(grads)
     ref_flat, _ = jax.tree.flatten(ref_grads)
@@ -55,3 +55,30 @@ def test_reference_matches_program_model():
     for g, r in zip(flat, ref_flat):
         scale = float(jnp.max(jnp.abs(r)))
         assert float(jnp.max(jnp.abs(g - r))) <= 1e-4 * scale + 1e-9
+
+
+# the tiny cell's first three reference steps (seed 3000000019, tokens
+# from default_rng(0)) as the reference read them before the model moved
+# into archs/dense.py
+PINNED_LOSSES = [6.02904748916626, 6.072024345397949, 6.003844261169434]
+PINNED_GRAD_NORMS = {
+    "embed": 0.05163555499166251, "final_norm": 0.13481729663908484,
+    "layers/bk": 0.006693116738460959, "layers/bq": 0.009112859261222185,
+    "layers/bv": 0.06255078595131637, "layers/ln1": 0.01911438652314246,
+    "layers/ln2": 0.036224490031600005, "layers/wdown": 0.25899717584252363,
+    "layers/wg": 0.1843296550214291, "layers/wi": 0.18110161647200587,
+    "layers/wk": 0.0739551614969969, "layers/wo": 0.09020064957439901,
+    "layers/wq": 0.0754158850759268, "layers/wv": 0.09385434910655023,
+    "lm_head": 0.3654003888368607}
+
+
+def test_tiny_reference_as_pinned_before_the_move():
+    seed = 3_000_000_019
+    rng = np.random.default_rng(0)
+    batches = [(rng.integers(1, tiny.CONFIG["vocab_size"], sum(lens)), lens)
+               for lens in traffic_gen.ordered(tiny.TRAFFIC, seed)[:3]]
+    ref = dense.run(dense.sizes(tiny.CONFIG),
+                    reference.Hyper.from_traffic(tiny.TRAFFIC), seed,
+                    batches)
+    assert ref.losses == PINNED_LOSSES
+    assert ref.grad_norms == PINNED_GRAD_NORMS

@@ -94,3 +94,48 @@ def test_synthetic_gaps_are_attributed():
     assert [n for n, _ in gaps] == ["bench.sync", "bench.plan"]
     assert [s for _, s in gaps] == pytest.approx([45e-9, 25e-9])
     assert trace_reduce.top_ops(v)[0] == ["a", pytest.approx(10e-9)]
+
+
+def test_synthetic_exposed_collectives():
+    """One P2P op wholly hidden under compute, one partly, one alone; a
+    second device with one P2P op alone.  The reader averages over the
+    devices and divides by the steps."""
+    import types
+
+    from chipbench import registry
+
+    e = trace_reduce.Event
+    v = trace_reduce.TraceView(
+        devices={
+            "/device:TPU:0": [
+                e("fusion.1", 0, 100),
+                e("collective-permute-done.1", 20, 20),     # hidden
+                e("collective-permute-start.2", 90, 30),    # 20 exposed
+                e("collective-permute-done.3", 150, 20),    # alone
+                e("all-reduce.4", 200, 30),                 # not P2P
+            ],
+            "/device:TPU:1": [e("fusion.1", 0, 100),
+                              e("collective-permute-done.1", 100, 10)],
+        },
+        host=[], window=(0, 300))
+    p2p = registry.metric("p2p_exposed_ms")
+    assert trace_reduce.exposed_s(v, p2p.P2P) == pytest.approx(
+        (40 + 10) / 2 * 1e-9)
+    assert trace_reduce.matching_s(v, p2p.P2P) == pytest.approx(
+        (70 + 10) / 2 * 1e-9)
+    run = types.SimpleNamespace(trace=v, steps=[[8], [8]])
+    assert p2p.read(run) == pytest.approx(25e-9 / 2 * 1e3)
+    # nothing to read: no trace, or no such op ran
+    assert p2p.read(types.SimpleNamespace(trace=None, steps=[[8]])) is None
+    quiet = trace_reduce.TraceView(
+        devices={"/device:TPU:0": [e("fusion.1", 0, 100),
+                                   e("all-reduce.4", 200, 30)]},
+        host=[], window=(0, 300))
+    assert p2p.read(types.SimpleNamespace(trace=quiet, steps=[[8]])) is None
+
+
+def test_op_name_from_hlo_text():
+    text = ("%fusion.12 = bf16[2240,5632]{1,0} fusion(%a, %b), kind=kCustom,"
+            " calls=%all-reduce-scatter.3, backend_config={}")
+    assert trace_reduce.op_name(text) == "fusion.12"
+    assert trace_reduce.op_name("%add.1 = f32[] add(%x, %y)") == "add.1"

@@ -1,7 +1,8 @@
 """A throwaway cell at a size the CPU runs in seconds: a copy of the
 benchmark's files in a temporary directory, plus a tiny configuration,
-traffic mix and limits of its own, and a ``BENCHMARK.json`` naming them.
-Nothing of the real benchmark's files is edited to add it."""
+traffic mix and limits of its own (and, where a test asks, an
+architecture or a metric of its own), and a ``BENCHMARK.json`` naming
+them.  Nothing of the real benchmark's files is edited to add it."""
 
 from __future__ import annotations
 
@@ -46,16 +47,26 @@ LIMITS = {"loss_gap": 2e-3, "grad_norm_gap": 6e-3, "update_norm_gap": 8e-3}
 
 
 def make(tmp: pathlib.Path, *, metric_source: str | None = None,
+         arch_source: str | None = None, traffic: dict | None = None,
          limits: dict | None = None) -> tuple[pathlib.Path, pathlib.Path]:
     """(root, bench_dir) of a copy holding the tiny cell.  With
     ``metric_source`` a new per-layer metric ``tiny_probe`` is added as
-    one more file and one more entry."""
+    one more file and one more entry; with ``arch_source`` a new
+    architecture ``tiny_toy``, which the tiny configuration names;
+    ``traffic`` replaces :data:`TRAFFIC`, and the cell asks for as many
+    chips as it has workers."""
     bench_dir = tmp / "benchmarks" / "chip"
     shutil.copytree(BENCH, bench_dir,
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
-    (bench_dir / "configs" / "tiny.json").write_text(json.dumps(CONFIG))
-    (bench_dir / "traffic" / "tiny_512_1w.json").write_text(
-        json.dumps(TRAFFIC))
+    config, traffic = dict(CONFIG), traffic or TRAFFIC
+    if arch_source is not None:
+        (bench_dir / "chipbench" / "archs" / "tiny_toy.py").write_text(
+            arch_source)
+        config["arch"] = "tiny_toy"
+    (bench_dir / "configs" / "tiny.json").write_text(json.dumps(config))
+    traffic_name = f"tiny_512_{traffic['workers']}w"
+    (bench_dir / "traffic" / f"{traffic_name}.json").write_text(
+        json.dumps(traffic))
     (bench_dir / "limits" / f"{CELL}.json").write_text(
         json.dumps(limits or LIMITS))
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -64,8 +75,8 @@ def make(tmp: pathlib.Path, *, metric_source: str | None = None,
         "file": "benchmarks/chip/configs/tiny.json", "reduced": [],
         "why": "CPU tests"})
     bench["workloads"].append({
-        "name": CELL, "config": "tiny", "traffic": "tiny_512_1w",
-        "chips": 1, "why": "CPU tests"})
+        "name": CELL, "config": "tiny", "traffic": traffic_name,
+        "chips": int(traffic["workers"]), "why": "CPU tests"})
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
             m["workloads"].append(CELL)
